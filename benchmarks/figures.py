@@ -11,16 +11,11 @@ Every function returns rows of (name, us_per_call, derived) for run.py's CSV.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _timeit(fn, *args, reps=3):
@@ -118,40 +113,25 @@ def fig7_vs_radix_baseline(sizes=(1_000_000, 4_000_000)):
 
 
 # ----------------------------------------------------------- figures 8-11 ---
-_DISTRIBUTED_SNIPPET = """
-import time, numpy as np, jax, jax.numpy as jnp
-from repro.core import distributed_merge_sort, cluster_sort, shared_memory_sort
-P = {P}; n = {n}
-mesh = jax.make_mesh((P,), ("x",))
-x = jnp.asarray(np.random.default_rng(0).integers(100, 1000, size=n).astype(np.int32))
-
-def timeit(fn):
-    out = fn(); jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(3): out = fn()
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / 3 * 1e6
-
-t_seq = timeit(lambda: jnp.sort(x))
-t_shared = timeit(lambda: shared_memory_sort(x, n_threads=4, local_impl="xla"))
-t_c = timeit(lambda: distributed_merge_sort(x, mesh, "x"))
-t_d = timeit(lambda: cluster_sort(x, mesh, "x", mode="range", lo=100, hi=1000,
-                                  capacity_factor=1.5)[0])
-print(f"RESULT,{{t_seq:.1f}},{{t_shared:.1f}},{{t_c:.1f}},{{t_d:.1f}}")
-"""
-
-
 def _run_distributed(P, n):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _DISTRIBUTED_SNIPPET.format(P=P, n=n)],
-        env=env, capture_output=True, text=True, timeout=900,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")][0]
-    return [float(v) for v in line.split(",")[1:]]
+    """Time sequential / model B / model C / model D on a (P,) mesh built
+    in this process from the first P devices (one process drives every
+    device; off-accelerator, set ``--xla_force_host_platform_device_count``
+    before JAX starts, as ``benchmarks/run.py`` does)."""
+    from repro.core import cluster_sort, distributed_merge_sort, shared_memory_sort
+
+    if jax.device_count() < P:
+        raise ValueError(f"a P={P} mesh needs {P} devices, this process has {jax.device_count()}")
+    mesh = jax.make_mesh((P,), ("x",), devices=jax.devices()[:P])
+    x = jnp.asarray(_data(n))
+
+    return [
+        _timeit(lambda: jnp.sort(x)),
+        _timeit(lambda: shared_memory_sort(x, n_threads=4, local_impl="xla")),
+        _timeit(lambda: distributed_merge_sort(x, mesh, "x")),
+        _timeit(lambda: cluster_sort(x, mesh, "x", mode="range", lo=100, hi=1000,
+                                     capacity_factor=1.5)[0]),
+    ]
 
 
 def fig8_distributed(n=1_000_000, P=4):

@@ -15,6 +15,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# figures 8-11 build their meshes in this process; off-accelerator the CPU
+# backend needs host devices for them before JAX starts (ignored on a TPU)
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 from benchmarks import figures  # noqa: E402
 

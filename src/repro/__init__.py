@@ -1,36 +1,9 @@
 """repro — parallel-sort reproduction framework.
 
-Import-time compat shims for jax API drift live here so every entry point
-(src modules, test subprocess snippets, examples) sees one consistent API.
-
 Public façade: ``repro.sort`` (the autotuned front door over the paper's four
 models) and the ``repro.engine`` subpackage (plans, key–value sorting, the
 batched serving service).  See ``docs/architecture.md`` for the layer map.
 """
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental (with check_vma spelled
-    # check_rep); newer jax promotes it to jax.shard_map.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # the old checker has false positives (e.g. psum inside scan) that the
-        # jax this codebase targets no longer flags — keep behaviour aligned
-        kwargs.setdefault("check_rep", False)
-        return _shard_map(f, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # psum of a constant folds to a Python int at trace time — the idiomatic
-    # axis-size query before jax grew lax.axis_size.
-    _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-
-# the shims above must be installed before any repro module touches jax,
-# so the façade import sits below them deliberately
-from repro.core.api import sort  # noqa: E402
+from repro.core.api import sort
 
 __all__ = ["sort"]

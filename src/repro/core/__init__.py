@@ -14,7 +14,7 @@ from .cluster_sort import (
     partition_exchange,
 )
 from .distributed_sort import distributed_merge_sort
-from .merge import merge_adjacent, merge_sorted_pair, rank_merge_pairs
+from .merge import merge_adjacent, merge_pairs, merge_sorted_pair
 from .radix import (
     choose_splitters,
     decimal_msd_bucket,
@@ -43,7 +43,7 @@ __all__ = [
     "distributed_merge_sort",
     "merge_adjacent",
     "merge_sorted_pair",
-    "rank_merge_pairs",
+    "merge_pairs",
     "shared_memory_sort",
     "nonrecursive_merge_sort",
     "recursive_merge_sort_host",

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.exchange import compact_slabs
+
 from .bitonic import sentinel_for
 from .merge import merge_sorted_pair
 from .seqsort import fast_local_sort
@@ -74,9 +76,9 @@ def distributed_merge_sort(
 ):
     """Sort 1-D ``x`` (length divisible by mesh axis size) across ``mesh[axis]``.
 
-    Returns the sorted array (gathered from device 0's buffer). Memory cost is
-    O(n) *per device* — the paper's design; use ``cluster_sort`` for the
-    scalable path. ``block_n`` tunes ``local_impl='pallas'``.
+    Returns the sorted array (device 0's buffer, sharded on ``axis``). Memory
+    cost is O(n) *per device* — the paper's design; use ``cluster_sort`` for
+    the scalable path. ``block_n`` tunes ``local_impl='pallas'``.
     """
     n = x.shape[-1]
     P_ = mesh.shape[axis]
@@ -85,7 +87,7 @@ def distributed_merge_sort(
 
     out = _compiled_merge_tree(mesh, axis, local_impl, block_n)(x)
     # device 0's buffer occupies the first n entries of the (P*n,) output
-    return out[:n]
+    return compact_slabs(out, jnp.arange(P_ * n) < n, n, mesh, axis)
 
 
 @lru_cache(maxsize=64)

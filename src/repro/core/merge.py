@@ -1,16 +1,15 @@
 """Vectorized merge of sorted runs — the paper's "merge & sort function".
 
-Two realizations of "merge two sorted lists of length w":
+``merge_pairs`` merges two sorted lists of length w with one stable
+``lax.sort`` of their concatenation: the left run comes first, so equal keys
+keep left-run-first order, which is merge sort's defining stability.  On TPU
+this is XLA's sort network over the pair; the rank formulation it replaced
+(output position = own index + rank in the other run, then a scatter) ran at
+a few million keys per second on a v5e, so one merge round at 2^27 keys took
+over a minute.
 
-* ``rank_merge_pairs`` — merge-path/rank based: the output position of every
-  element is its own index plus its rank in the other list (``searchsorted``),
-  then a scatter. O(n log w) work, one gatherless scatter; this is the
-  TPU-friendly analogue of the paper's sequential two-pointer merge.
-  ``searchsorted`` sides are chosen so the merge is *stable* (left-run elements
-  precede equal right-run elements), matching merge sort's defining property.
-
-* ``bitonic`` merge (see ``bitonic.py``) — branch-free compare-exchange network;
-  used inside the Pallas kernel where scatters are awkward.
+``bitonic`` merge (see ``bitonic.py``) is the branch-free compare-exchange
+network used inside the Pallas kernel.
 
 ``merge_adjacent`` performs one round of the paper's bottom-up merge: an array
 viewed as ``r`` sorted runs of width ``w`` becomes ``r/2`` sorted runs of width
@@ -24,56 +23,27 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-__all__ = ["rank_merge_pairs", "merge_adjacent", "merge_sorted_pair"]
+__all__ = ["merge_pairs", "merge_adjacent", "merge_sorted_pair"]
 
 
 @partial(jax.jit, static_argnames=("has_values",))
-def _rank_merge(pairs, values, *, has_values: bool):
+def _merge(pairs, values, *, has_values: bool):
     """pairs: (..., 2, w) two sorted runs -> (..., 2w) merged, stable."""
-    a = pairs[..., 0, :]
-    b = pairs[..., 1, :]
-    w = a.shape[-1]
-    # rank of a[i] among b (left side: a wins ties -> stable) and vice versa
-    pos_a = jnp.arange(w) + _searchsorted(b, a, side="left")
-    pos_b = jnp.arange(w) + _searchsorted(a, b, side="right")
-    inv = _invert_perm(jnp.concatenate([pos_a, pos_b], axis=-1))
-    out = jnp.take_along_axis(  # scatter via inverse permutation
-        jnp.concatenate([a, b], axis=-1), inv, axis=-1
+    *lead, _, w = pairs.shape
+    leaves, treedef = jax.tree.flatten(values)
+    out = jax.lax.sort(
+        [a.reshape(*lead, 2 * w) for a in (pairs, *leaves)],
+        dimension=len(lead),
+        is_stable=True,
+        num_keys=1,
     )
-    if not has_values:
-        return out, None
-    merged_vals = jax.tree.map(
-        lambda v: jnp.take_along_axis(
-            jnp.concatenate([v[..., 0, :], v[..., 1, :]], axis=-1), inv, axis=-1
-        ),
-        values,
-    )
-    return out, merged_vals
+    return out[0], (jax.tree.unflatten(treedef, out[1:]) if has_values else None)
 
 
-def _searchsorted(sorted_arr, query, *, side):
-    """Batched searchsorted along the last axis (vmapped over leading dims)."""
-    flat_s = sorted_arr.reshape(-1, sorted_arr.shape[-1])
-    flat_q = query.reshape(-1, query.shape[-1])
-    out = jax.vmap(lambda s, q: jnp.searchsorted(s, q, side=side))(flat_s, flat_q)
-    return out.reshape(query.shape)
-
-
-def _invert_perm(perm):
-    """Invert a permutation given along the last axis."""
-    iota = jnp.broadcast_to(jnp.arange(perm.shape[-1], dtype=perm.dtype), perm.shape)
-    flat_p = perm.reshape(-1, perm.shape[-1])
-    flat_i = iota.reshape(-1, iota.shape[-1])
-
-    def one(p, i):
-        return jnp.zeros_like(p).at[p].set(i)
-
-    return jax.vmap(one)(flat_p, flat_i).reshape(perm.shape)
-
-
-def rank_merge_pairs(pairs, values=None):
-    """Merge (..., 2, w) sorted-run pairs into (..., 2w) stably."""
-    out, vals = _rank_merge(pairs, values, has_values=values is not None)
+def merge_pairs(pairs, values=None):
+    """Merge (..., 2, w) sorted-run pairs into (..., 2w) stably; ``values``
+    leaves shaped like ``pairs`` ride along."""
+    out, vals = _merge(pairs, values, has_values=values is not None)
     return out if values is None else (out, vals)
 
 
@@ -81,9 +51,9 @@ def merge_sorted_pair(a, b, va=None, vb=None):
     """Stable merge of two sorted arrays along the last axis (equal length)."""
     pairs = jnp.stack([a, b], axis=-2)
     if va is None:
-        return rank_merge_pairs(pairs)
+        return merge_pairs(pairs)
     values = jax.tree.map(lambda x, y: jnp.stack([x, y], axis=-2), va, vb)
-    return rank_merge_pairs(pairs, values)
+    return merge_pairs(pairs, values)
 
 
 def merge_adjacent(x, width: int, values=None):
@@ -95,12 +65,16 @@ def merge_adjacent(x, width: int, values=None):
     """
     *lead, n = x.shape
     assert n % (2 * width) == 0, (n, width)
+    # keep XLA from fusing whatever produced the runs (a sort, the previous
+    # round) into this round's merge: for a v5e that fusion compiled for
+    # minutes at 2^27 keys
+    x, values = jax.lax.optimization_barrier((x, values))
     pairs = x.reshape(*lead, n // (2 * width), 2, width)
     if values is None:
-        merged = rank_merge_pairs(pairs)
+        merged = merge_pairs(pairs)
         return merged.reshape(*lead, n)
     vals = jax.tree.map(lambda v: v.reshape(*lead, n // (2 * width), 2, width), values)
-    merged, mvals = rank_merge_pairs(pairs, vals)
+    merged, mvals = merge_pairs(pairs, vals)
     return merged.reshape(*lead, n), jax.tree.map(
         lambda v: v.reshape(*lead, n), mvals
     )

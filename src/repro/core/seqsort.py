@@ -66,7 +66,7 @@ def nonrecursive_merge_sort(x: jax.Array, *, ascending: bool = True) -> jax.Arra
     """Paper Fig 1(b): bottom-up merge sort, each round fully vectorized.
 
     Pads to a power of two with sentinels; log2(n) rounds of ``merge_adjacent``.
-    Stable (rank merge breaks ties left-first).
+    Stable (every merge keeps left-run-first ties).
     """
     from .bitonic import next_pow2, sentinel_for
 

@@ -16,7 +16,19 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+
+def auto_mesh(mesh):
+    """The mesh the model stack runs on: ``mesh`` with every axis Auto.
+
+    The model pins activations with sharding constraints and leaves the rest
+    to XLA's propagation, which Explicit axes (``jax.make_mesh``'s default)
+    refuse. Same devices and axis names, so a caller's default mesh works.
+    """
+    if AxisType.Explicit not in mesh.axis_types:
+        return mesh
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _path_names(kp) -> tuple:
@@ -160,7 +172,9 @@ def fit_spec(shape, spec: P, mesh) -> P:
 
 
 def to_named(tree_specs, mesh, like=None) -> Any:
-    """Specs -> NamedShardings; with ``like`` (shape tree), fit per-dim."""
+    """Specs -> NamedShardings on ``auto_mesh(mesh)``; with ``like`` (shape
+    tree), fit per-dim."""
+    mesh = auto_mesh(mesh)
     if like is None:
         valid = set(mesh.axis_names)
 

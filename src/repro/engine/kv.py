@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.radix import make_partitioner
 from repro.exchange import (
+    compact_slabs,
     partition_exchange,
     partition_of,
     run_with_capacity_retries,
@@ -178,13 +179,14 @@ def cluster_sort_kv(
     recompile events) through the optional ``telemetry`` callback that
     ``repro.engine.adapt`` turns into learned capacity factors.
 
-    >>> import jax, jax.numpy as jnp
+    >>> import jax, jax.numpy as jnp, numpy as np
     >>> mesh = jax.make_mesh((jax.device_count(),), ("x",))
     >>> keys = jnp.arange(16)[::-1]
     >>> slab, vals, valid = cluster_sort_kv(keys, {"i": jnp.arange(16)}, mesh, "x")
-    >>> [int(v) for v in slab[valid][:4]]
+    >>> k, v = compact_slabs((slab, vals), valid, 16, mesh, "x")
+    >>> [int(x) for x in np.asarray(k)[:4]]
     [0, 1, 2, 3]
-    >>> [int(v) for v in vals["i"][valid][:4]]   # payload rides along
+    >>> [int(x) for x in np.asarray(v["i"])[:4]]   # payload rides along
     [15, 14, 13, 12]
     """
     P_ = mesh.shape[axis]
@@ -230,7 +232,7 @@ def sort_kv(
     picks the local argsort engine ('xla' or 'pallas', ``block_n`` = kernel
     tile width; only 'xla' totally orders NaN keys).  With ``mesh=``/
     ``axis=``: 1-D keys, model-D exchange of full records, returns dense
-    (n,)-shaped results (the slab is compacted eagerly).  The mesh path
+    (n,)-shaped results sharded on ``axis`` (``compact_slabs``).  The mesh path
     closes the capacity-learning loop by default — it runs at the default
     planner's learned ``capacity_factor`` for this (size, dtype, mesh) cell
     and reports exchange telemetry back (pass ``capacity_factor=`` or
@@ -276,7 +278,7 @@ def sort_kv(
     slab_k, slab_v, valid = cluster_sort_kv(
         keys, values, mesh, axis, compress=compress, **cluster_kw
     )
-    return slab_k[valid], jax.tree.map(lambda a: a[valid], slab_v)
+    return compact_slabs((slab_k, slab_v), valid, keys.shape[-1], mesh, axis)
 
 
 def sort_pairs(keys: jax.Array, values: jax.Array, **kwargs):

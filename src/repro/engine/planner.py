@@ -369,9 +369,7 @@ def _dist_barrier(tag: str) -> None:
 def _max_over_ranks(value: float) -> float:
     """Reduce one per-rank scalar to its max across all processes.
 
-    Every rank must call this (it is a collective); a rank whose candidate
-    failed contributes ``inf``, which poisons the candidate everywhere —
-    a plan only some ranks can run is not a plan.
+    Every rank must call this (it is a collective).
     """
     import numpy as np
     from jax.experimental import multihost_utils
@@ -937,22 +935,10 @@ class Planner:
             if distributed:
                 _dist_barrier(f"autotune:{key}:{i}")
             arr = x if cand.strategy == "shared" else x_mesh
-            try:
-                times = _time_plan_reps(cand, arr, mesh, axis, reps=reps, **kwargs)
-                us = _median(times) if distributed else sum(times) / len(times)
-            except Exception:
-                if cand.local_impl != "pallas":
-                    raise
-                # a pallas tile the local Mosaic/backend can't lower is a
-                # skipped candidate, not a failed sweep — but a distributed
-                # rank still owes the reduction its (poisoned) score
-                if not distributed:
-                    continue
-                us = float("inf")
+            times = _time_plan_reps(cand, arr, mesh, axis, reps=reps, **kwargs)
+            us = _median(times) if distributed else sum(times) / len(times)
             if distributed:
                 us = _max_over_ranks(us)
-                if us == float("inf"):
-                    continue
             cand = replace(cand, us_per_call=round(us, 2))
             if best is None or cand.us_per_call < best.us_per_call:
                 best = cand

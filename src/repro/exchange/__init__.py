@@ -16,7 +16,7 @@ Modules:
 
 slabs      : slab/capacity math — ``sentinel_for``, ``slab_capacity``,
              ``slab_geometry`` (model D), ``expert_capacity`` (MoE),
-             ``slab_valid``
+             ``slab_valid`` and ``compact_slabs`` (slab -> dense result)
 collective : the wire — ``partition_exchange`` / ``combine_exchange`` /
              ``ExchangeResult`` (single all_to_all each way, optional int8
              compression)
@@ -46,6 +46,7 @@ from .partition import (
 )
 from .retry import run_with_capacity_retries
 from .slabs import (
+    compact_slabs,
     expert_capacity,
     sentinel_for,
     slab_capacity,
@@ -63,6 +64,7 @@ __all__ = [
     "ExchangeTelemetry",
     "choose_splitters",
     "combine_exchange",
+    "compact_slabs",
     "expert_capacity",
     "partition_exchange",
     "partition_of",

@@ -188,7 +188,7 @@ def choose_splitters(
     >>> mesh = jax.make_mesh((jax.device_count(),), ("x",))
     >>> f = jax.jit(jax.shard_map(
     ...     lambda k: choose_splitters(k, 4, "x"),
-    ...     mesh=mesh, in_specs=P("x"), out_specs=P()))
+    ...     mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False))
     >>> spl = f(jnp.arange(64.0))
     >>> bool(jnp.all(spl[:-1] <= spl[1:]))     # sorted, B-1 of them
     True
@@ -257,14 +257,14 @@ def sample_partition_ids(
     Monotone in the composite order, hence in key order:
     ``k1 <= k2`` implies ``bucket(k1) <= bucket(k2)``.
 
-    >>> import jax, jax.numpy as jnp, repro
+    >>> import jax, jax.numpy as jnp, numpy as np, repro
     >>> from jax.sharding import PartitionSpec as P
     >>> mesh = jax.make_mesh((jax.device_count(),), ("x",))
     >>> f = jax.jit(jax.shard_map(
     ...     lambda k: sample_partition_ids(k, 4, "x"),
     ...     mesh=mesh, in_specs=P("x"), out_specs=P("x")))
     >>> b = f(jnp.zeros(64, jnp.int32))        # all-equal keys still balance
-    >>> [int(c) for c in jnp.bincount(b, length=4)]    # even to within one
+    >>> [int(c) for c in np.bincount(np.asarray(b), minlength=4)]  # even to within one
     [17, 16, 16, 15]
     """
     P_ = jax.lax.axis_size(axis_name)

@@ -8,9 +8,14 @@ formula, so the two paths can never drift apart.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 __all__ = [
+    "compact_slabs",
     "expert_capacity",
     "sentinel_for",
     "slab_capacity",
@@ -123,3 +128,46 @@ def slab_valid(total: int, counts, P_: int):
     C_total = total // P_
     pos = jnp.arange(total) % C_total
     return pos < jnp.repeat(counts, C_total)
+
+
+def compact_slabs(tree, valid, n: int, mesh, axis: str):
+    """Dense ``(n, ...)`` form of a gathered result slab, sharded on ``axis``.
+
+    ``tree`` is an array or pytree of arrays laid out like a ``slab_valid``
+    slab (``(P_ * C_total, ...)``, sharded ``P(axis)``) and ``valid`` marks a
+    prefix of every shard's ``C_total`` slots, ``n`` entries in all.  The
+    valid entries come back in slab order as ``(n, ...)`` arrays sharded
+    ``P(axis)``.  The compaction is one jitted ``shard_map`` with static
+    shapes, so it runs the same on Auto and Explicit mesh axes (an eager
+    boolean-mask index does not).
+
+    >>> import jax, jax.numpy as jnp
+    >>> mesh = jax.make_mesh((1,), ("x",))
+    >>> slab = jnp.array([4, 7, 0, 0])
+    >>> [int(v) for v in compact_slabs(slab, slab_valid(4, jnp.array([2]), 1), 2, mesh, "x")]
+    [4, 7]
+    """
+    return _compiled_compact(mesh, axis, n)(tree, valid)
+
+
+@functools.lru_cache(maxsize=64)
+def _compiled_compact(mesh, axis: str, n: int):
+    P_ = mesh.shape[axis]
+    if n % P_:
+        raise ValueError(f"n={n} must divide axis size {P_}")
+    m = n // P_
+
+    def body(tree, valid):
+        C_total = valid.shape[0]
+        counts = jax.lax.all_gather(jnp.sum(valid, dtype=jnp.int32), axis)
+        ends = jnp.cumsum(counts)
+        g = jax.lax.axis_index(axis) * m + jnp.arange(m, dtype=jnp.int32)
+        owner = jnp.searchsorted(ends, g, side="right")
+        src = owner * C_total + g - (ends[owner] - counts[owner])
+        return jax.tree.map(
+            lambda a: jax.lax.all_gather(a, axis, tiled=True)[src], tree
+        )
+
+    return jax.jit(
+        jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(axis))
+    )

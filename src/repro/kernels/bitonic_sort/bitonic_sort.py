@@ -3,37 +3,36 @@
 Decomposition (see ref.py): the canonical n-element network is split so that
 every O(log^2 block_n) "local" substage runs inside VMEM, and only the
 O(log^2 (n/block_n)) cross-block substages touch HBM between kernel launches.
-For block_n = 8192 fp32 that is a 32 KiB working set per program — well inside
-the ~16 MiB VMEM budget even with double buffering, and every compare-exchange
-is a branch-free ``min``/``max`` on VREG lanes (VPU work; the MXU is idle by
-design — sorting is a bandwidth problem).
+Every compare-exchange is a branch-free select on VREG lanes (VPU work; the
+MXU is idle by design — sorting is a bandwidth problem).
 
 Kernels:
-  A  _block_sort_kernel   per-block full network, direction alternating by
-                          block parity (grid = n/block_n programs)
-  B  _block_merge_kernel  all substages j < block_n of one merge stage k in a
-                          single VMEM pass (the perf-critical fusion: log2(bn)
-                          HBM round-trips collapse into one)
+  A  block_sort    per-block full network, direction alternating by block
+                   parity
+  B  block_merge   all substages j < block_n of one merge stage k in a single
+                   VMEM pass (the perf-critical fusion: log2(bn) HBM
+                   round-trips collapse into one)
   C  cross-block substages j >= block_n: one elementwise compare-exchange over
      block pairs, expressed at the jnp level (pure bandwidth, no reuse to
      exploit — XLA emits the optimal elementwise kernel for it).
 
-Each kernel also has a ``*_kv`` twin that carries an int32 rank array through
-the same network with a lexicographic (key, rank) comparator.  Ranks start as
-iota, ranks never tie, so the comparator is a total order and the rank output
-is the *stable* sorting permutation — that one permutation is what
-``ops.pallas_argsort`` / ``ops.pallas_sort_kv`` gather arbitrary value
-payloads with.  Carrying ranks doubles the VMEM working set per program
-(still tiny: 2 * 4 B * block_n) and stays branch-free on VREG lanes.
+A and B share one kernel body, ``_network_kernel``; each also has a ``*_kv``
+twin that carries an int32 rank array through the same network with a
+lexicographic (key, rank) comparator.  Ranks start as iota, ranks never tie,
+so the comparator is a total order and the rank output is the *stable*
+sorting permutation — that one permutation is what ``ops.pallas_argsort`` /
+``ops.pallas_sort_kv`` gather arbitrary value payloads with.
 
-TPU layout note: blocks are processed as (block_n,) vectors; the power-of-two
-reshapes inside the network lower to lane shuffles/rolls on Mosaic. Any pow2
-block_n works (the wrapper clamps it to the padded problem size, and the
-planner sweeps 256/512/1024); multiples of 1024 keep every sub-reshape
-lane-aligned and are the perf-preferred choice on real TPUs — autotune skips
-any candidate whose lowering fails, so an unsupported tile on some Mosaic
-version degrades to "not selected", never a crash. Validated element-exact
-against ref.py in interpret mode (CPU) — the TPU is the target.
+TPU layout: the 1-D input is viewed as rows of 128 lanes, and each program
+owns a ``(rows, 128)`` tile of ``max(block_n, TILE)`` elements (the whole
+input when it is shorter), so a program holds whole (8, 128) vregs even when
+``block_n`` is smaller: it then runs several blocks side by side.  Element ``p`` of the flat
+array sits at row ``p // 128``, lane ``p % 128``.  The partner at distance
+``j`` is fetched without any in-kernel reshape: for ``j < 128`` with a lane
+roll, for ``j >= 128`` with a sublane roll of ``j / 128`` rows, and the
+element's own position bit ``p & j`` picks which of the two rolls holds it.
+Mosaic rejects the shape casts a ``(g, 2, j)`` reshape of a 1-D block needs,
+which is why the network is written this way.
 
 Comparator caveat (shared with the pure-jnp network in core/bitonic.py): the
 compare-exchange uses ``>``, under which NaN compares false everywhere — NaN
@@ -47,142 +46,129 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.bitonic import _compare_exchange
+
+LANES = 128
+# elements per program: one (8, 128) tile of 32-bit vregs
+TILE = 8 * LANES
 
 
-def _ce_flat(x, j: int, dir_up_vec):
-    """Compare-exchange at distance j on a flat (n,) array (in-kernel body)."""
-    n = x.shape[-1]
-    g = n // (2 * j)
-    x2 = x.reshape(g, 2, j)
-    a, b = x2[:, 0, :], x2[:, 1, :]
-    swap = (a > b) == dir_up_vec[:, None]
-    lo = jnp.where(swap, b, a)
-    hi = jnp.where(swap, a, b)
-    return jnp.stack([lo, hi], axis=1).reshape(n)
+def _tile_shape(n: int, block_n: int):
+    """(rows, lanes) of one program's tile for an n-element 1-D input."""
+    tile = min(n, max(block_n, TILE))
+    lanes = min(tile, LANES)
+    return tile // lanes, lanes
 
 
-def _ce_flat_kv(x, r, j: int, dir_up_vec):
-    """Compare-exchange carrying ranks: lexicographic (key, rank) comparator.
+def _partner(x, j: int, pos):
+    """Value at flat position ``pos ^ j`` for every element of a 2-D tile.
 
-    Ranks are unique, so ``gt`` is a strict total order — equal keys order by
-    original rank, which is exactly the stable permutation.
+    ``roll(x, s)[i] == x[i - s]`` along the axis, so the element whose bit
+    ``j`` is clear reads its partner from the roll by ``size - d`` and the one
+    whose bit is set from the roll by ``d``.
     """
-    n = x.shape[-1]
-    g = n // (2 * j)
-    x2 = x.reshape(g, 2, j)
-    r2 = r.reshape(g, 2, j)
-    a, b = x2[:, 0, :], x2[:, 1, :]
-    ra, rb = r2[:, 0, :], r2[:, 1, :]
-    gt = (a > b) | ((a == b) & (ra > rb))
-    swap = gt == dir_up_vec[:, None]
-    lo = jnp.where(swap, b, a)
-    hi = jnp.where(swap, a, b)
-    rlo = jnp.where(swap, rb, ra)
-    rhi = jnp.where(swap, ra, rb)
-    return (
-        jnp.stack([lo, hi], axis=1).reshape(n),
-        jnp.stack([rlo, rhi], axis=1).reshape(n),
+    rows, lanes = x.shape
+    axis, d, size = (1, j, lanes) if j < lanes else (0, j // lanes, rows)
+    ahead = pltpu.roll(x, size - d, axis)
+    behind = pltpu.roll(x, d, axis)
+    return jnp.where((pos & j) == 0, ahead, behind)
+
+
+def _network_kernel(*refs, block_n: int, k: int | None, kv: bool):
+    """Kernel A (``k is None``) or kernel B (merge stage ``k``) on one tile.
+
+    Kernel A sorts every aligned block of ``block_n`` elements, ascending iff
+    the block index is even. Kernel B runs substages j = block_n/2 .. 1 of
+    stage ``k > block_n``, whose direction is uniform inside a block: up iff
+    ``(pos & k) == 0``.
+    """
+    if kv:
+        x_ref, r_ref, ox_ref, or_ref = refs
+        r = r_ref[...]
+    else:
+        x_ref, ox_ref = refs
+    x = x_ref[...]
+    rows, lanes = x.shape
+    pos = (
+        pl.program_id(0) * (rows * lanes)
+        + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * lanes
+        + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     )
-
-
-def _block_sort_kernel(x_ref, o_ref, *, block_n: int):
-    """Kernel A body: canonical network on one block; direction = block parity."""
-    b = pl.program_id(0)
-    asc = (b % 2) == 0  # traced bool; fold into comparator via XOR
-    x = x_ref[...]
-    log_n = block_n.bit_length() - 1
-    for stage in range(1, log_n + 1):
-        k = 1 << stage
-        for sub in range(stage - 1, -1, -1):
-            j = 1 << sub
-            g = block_n // (2 * j)
-            blk = (jnp.arange(g) * 2 * j) // k
-            dir_up = (blk % 2 == 0) == asc
-            x = _ce_flat(x, j, dir_up)
-    o_ref[...] = x
-
-
-def _block_sort_kv_kernel(x_ref, r_ref, ox_ref, or_ref, *, block_n: int):
-    """Kernel A (kv twin): (key, rank) network on one block, parity direction."""
-    b = pl.program_id(0)
-    asc = (b % 2) == 0
-    x = x_ref[...]
-    r = r_ref[...]
-    log_n = block_n.bit_length() - 1
-    for stage in range(1, log_n + 1):
-        k = 1 << stage
-        for sub in range(stage - 1, -1, -1):
-            j = 1 << sub
-            g = block_n // (2 * j)
-            blk = (jnp.arange(g) * 2 * j) // k
-            dir_up = (blk % 2 == 0) == asc
-            x, r = _ce_flat_kv(x, r, j, dir_up)
+    log_bn = block_n.bit_length() - 1
+    if k is None:  # kernel A: every stage of the network, inside each block
+        block_up = (pos & block_n) == 0
+        stages = [(1 << s, 1 << t) for s in range(1, log_bn + 1) for t in reversed(range(s))]
+    else:  # kernel B: the in-block substages of merge stage k
+        stages = [(k, 1 << t) for t in reversed(range(log_bn))]
+    for kk, j in stages:
+        if k is None:
+            # the block's last stage (kk == block_n) runs in the block's own
+            # direction; earlier ones alternate with bit kk of the position
+            up = ((pos & kk & (block_n - 1)) == 0) == block_up
+        else:
+            up = (pos & kk) == 0
+        lower = (pos & j) == 0
+        p = _partner(x, j, pos)
+        a = jnp.where(lower, x, p)  # key of the pair's lower position
+        b = jnp.where(lower, p, x)
+        gt = a > b
+        if kv:
+            rp = _partner(r, j, pos)
+            ra = jnp.where(lower, r, rp)
+            rb = jnp.where(lower, rp, r)
+            gt = gt | ((a == b) & (ra > rb))
+        swap = gt == up
+        x = jnp.where(swap, p, x)
+        if kv:
+            r = jnp.where(swap, rp, r)
     ox_ref[...] = x
-    or_ref[...] = r
+    if kv:
+        or_ref[...] = r
 
 
-def _block_merge_kernel(x_ref, o_ref, *, block_n: int, k: int):
-    """Kernel B body: substages j = block_n/2 .. 1 of stage k, fused in VMEM.
-
-    Stage k > block_n implies the comparator direction is uniform inside the
-    block: up iff (block_start & k) == 0.
-    """
-    b = pl.program_id(0)
-    up = ((b * block_n) & k) == 0
-    x = x_ref[...]
-    sub = block_n // 2
-    while sub >= 1:
-        j = sub
-        g = block_n // (2 * j)
-        dir_up = jnp.full((g,), True) == up
-        x = _ce_flat(x, j, dir_up)
-        sub //= 2
-    o_ref[...] = x
-
-
-def _block_merge_kv_kernel(x_ref, r_ref, ox_ref, or_ref, *, block_n: int, k: int):
-    """Kernel B (kv twin): fused local substages of stage k with ranks."""
-    b = pl.program_id(0)
-    up = ((b * block_n) & k) == 0
-    x = x_ref[...]
-    r = r_ref[...]
-    sub = block_n // 2
-    while sub >= 1:
-        j = sub
-        g = block_n // (2 * j)
-        dir_up = jnp.full((g,), True) == up
-        x, r = _ce_flat_kv(x, r, j, dir_up)
-        sub //= 2
-    ox_ref[...] = x
-    or_ref[...] = r
+def _launch(arrays, block_n: int, k: int | None, interpret: bool):
+    n = arrays[0].shape[-1]
+    rows, lanes = _tile_shape(n, block_n)
+    spec = pl.BlockSpec((rows, lanes), lambda b: (b, 0))
+    tiled = [a.reshape(n // lanes, lanes) for a in arrays]
+    out = pl.pallas_call(
+        functools.partial(_network_kernel, block_n=block_n, k=k, kv=len(arrays) == 2),
+        grid=(n // (rows * lanes),),
+        in_specs=[spec] * len(arrays),
+        out_specs=[spec] * len(arrays),
+        # vma: inside shard_map the outputs vary over the same mesh axes
+        out_shape=[
+            jax.ShapeDtypeStruct(a.shape, a.dtype, vma=jax.typeof(a).vma) for a in tiled
+        ],
+        interpret=interpret,
+    )(*tiled)
+    return [o.reshape(n) for o in out]
 
 
 def block_sort(x: jax.Array, block_n: int, *, interpret: bool) -> jax.Array:
     """Launch kernel A over all aligned blocks of the last axis (1-D x)."""
-    n = x.shape[-1]
-    nb = n // block_n
-    return pl.pallas_call(
-        functools.partial(_block_sort_kernel, block_n=block_n),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block_n,), lambda b: (b,))],
-        out_specs=pl.BlockSpec((block_n,), lambda b: (b,)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
+    return _launch([x], block_n, None, interpret)[0]
 
 
 def block_merge(x: jax.Array, block_n: int, k: int, *, interpret: bool) -> jax.Array:
     """Launch kernel B (fused local substages of stage k) over all blocks."""
-    n = x.shape[-1]
-    nb = n // block_n
-    return pl.pallas_call(
-        functools.partial(_block_merge_kernel, block_n=block_n, k=k),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block_n,), lambda b: (b,))],
-        out_specs=pl.BlockSpec((block_n,), lambda b: (b,)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
+    return _launch([x], block_n, k, interpret)[0]
+
+
+def block_sort_kv(x: jax.Array, r: jax.Array, block_n: int, *, interpret: bool):
+    """Launch kernel A (kv twin): returns (keys, ranks) per-block sorted."""
+    return tuple(_launch([x, r], block_n, None, interpret))
+
+
+def block_merge_kv(x: jax.Array, r: jax.Array, block_n: int, k: int, *, interpret: bool):
+    """Launch kernel B (kv twin) over all blocks."""
+    return tuple(_launch([x, r], block_n, k, interpret))
+
+
+def _global_dir(n: int, j: int, k: int):
+    return ((jnp.arange(n // (2 * j)) * 2 * j) // k) % 2 == 0
 
 
 def global_stage(x: jax.Array, j: int, k: int) -> jax.Array:
@@ -191,58 +177,10 @@ def global_stage(x: jax.Array, j: int, k: int) -> jax.Array:
     Pure-bandwidth step with zero data reuse; left at the jnp level where XLA
     already emits a single fused elementwise kernel (Design choice C above).
     """
-    n = x.shape[-1]
-    g = n // (2 * j)
-    dir_up = ((jnp.arange(g) * 2 * j) // k) % 2 == 0
-    x2 = x.reshape(g, 2, j)
-    a, b = x2[:, 0, :], x2[:, 1, :]
-    swap = (a > b) == dir_up[:, None]
-    lo = jnp.where(swap, b, a)
-    hi = jnp.where(swap, a, b)
-    return jnp.stack([lo, hi], axis=1).reshape(n)
-
-
-def _kv_specs(block_n: int):
-    spec = pl.BlockSpec((block_n,), lambda b: (b,))
-    return [spec, spec], [spec, spec]
-
-
-def block_sort_kv(x: jax.Array, r: jax.Array, block_n: int, *, interpret: bool):
-    """Launch kernel A (kv twin): returns (keys, ranks) per-block sorted."""
-    nb = x.shape[-1] // block_n
-    in_specs, out_specs = _kv_specs(block_n)
-    return pl.pallas_call(
-        functools.partial(_block_sort_kv_kernel, block_n=block_n),
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(r.shape, r.dtype),
-        ],
-        interpret=interpret,
-    )(x, r)
-
-
-def block_merge_kv(x: jax.Array, r: jax.Array, block_n: int, k: int, *, interpret: bool):
-    """Launch kernel B (kv twin) over all blocks."""
-    nb = x.shape[-1] // block_n
-    in_specs, out_specs = _kv_specs(block_n)
-    return pl.pallas_call(
-        functools.partial(_block_merge_kv_kernel, block_n=block_n, k=k),
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(r.shape, r.dtype),
-        ],
-        interpret=interpret,
-    )(x, r)
+    return _compare_exchange(x, None, None, j, _global_dir(x.shape[-1], j, k), ascending=True)[0]
 
 
 def global_stage_kv(x: jax.Array, r: jax.Array, j: int, k: int):
     """Cross-block substage (kv twin): (key, rank) compare-exchange at jnp level."""
-    g = x.shape[-1] // (2 * j)
-    dir_up = ((jnp.arange(g) * 2 * j) // k) % 2 == 0
-    return _ce_flat_kv(x, r, j, dir_up)
+    x, r, _ = _compare_exchange(x, r, None, j, _global_dir(x.shape[-1], j, k), ascending=True)
+    return x, r

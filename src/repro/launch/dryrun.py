@@ -39,6 +39,7 @@ from repro.distributed.sharding import (
     param_specs,
     to_named,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import ModelConfig, ShardCtx, init_cache, model_init
 from repro.optim.adamw import OptConfig, init_opt_state
@@ -260,6 +261,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, outdir: str) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default=None)
     ap.add_argument("--shape", choices=sorted(SHAPES), default=None)

@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.configs.base import ARCHS, reduced
 from repro.engine import topk
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import ShardCtx, model_init
 from repro.train.steps import prefill_step, serve_decode_step
 
@@ -192,6 +193,7 @@ def run_moe_serving(args):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")

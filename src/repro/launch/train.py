@@ -35,6 +35,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ARCHS, reduced
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.distributed.fault_tolerance import AnomalyMonitor, run_with_recovery
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import ShardCtx, model_init
 from repro.optim.adamw import OptConfig, init_opt_state
 from repro.train.adaptive import MoECapacityController, parse_mesh_spec
@@ -46,6 +47,7 @@ def _has_moe(cfg) -> bool:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true", help="smoke-size config")

@@ -263,6 +263,12 @@ class ShardCtx:
     axes: Tuple[str, ...] = ()      # all mesh axis names, batch shards over them
     ep_axis: str = "model"
 
+    def __post_init__(self):
+        if self.mesh is not None:
+            from repro.distributed.sharding import auto_mesh
+
+            object.__setattr__(self, "mesh", auto_mesh(self.mesh))
+
     @property
     def ep_shards(self) -> int:
         return 1 if self.mesh is None else self.mesh.shape[self.ep_axis]

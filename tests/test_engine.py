@@ -79,6 +79,25 @@ def test_autotune_sweeps_pallas_and_roundtrips_block_n(tmp_path):
     assert got.local_impl == "pallas" and got.block_n == 512
 
 
+def test_autotune_raises_when_a_pallas_candidate_fails_to_compile(tmp_path, monkeypatch):
+    """A tile width the backend refuses fails the sweep loudly: it is never
+    quietly dropped from the candidates."""
+    from repro.kernels.bitonic_sort import ops
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(ops, "_pallas_sort_impl", refuse)
+    planner = Planner(str(tmp_path / "plans.json"))
+    cands = [
+        SortPlan("shared", local_impl="xla"),
+        SortPlan("shared", local_impl="pallas", block_n=16),
+    ]
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        planner.autotune(48, jnp.int32, reps=1, candidates=cands)
+    assert planner.lookup(48, jnp.int32) is None
+
+
 def test_api_sort_pallas_local_impl_matches_numpy():
     """Acceptance: sort(x, strategy='shared', local_impl='pallas') == np.sort
     for non-pow2 and batched inputs (interpret mode on this container)."""
@@ -240,7 +259,7 @@ def test_service_zero_recompiles_for_same_bucket_traffic():
     second = [rng.integers(0, 1000, n).astype(np.int32) for n in (900, 700, 400)]
     with jtu.count_jit_and_pmap_lowerings() as count:
         out2 = svc.submit(second)
-    assert count[0] == 0, "serving hot path must not re-trace"
+    assert count() == 0, "serving hot path must not re-trace"
     assert svc.cache.misses == compiles_after_first
     for r, o in zip(second, out2):
         assert (o == np.sort(r)).all()

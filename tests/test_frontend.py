@@ -245,7 +245,7 @@ def test_warmup_then_zero_lowerings_on_warmed_traffic():
             for kind in ("sort", "argsort") for n in (400, 500, 900)
         ]
         fe.poll()
-    assert count[0] == 0, "warmed cells must never re-trace"
+    assert count() == 0, "warmed cells must never re-trace"
     for t in tickets[:3]:
         assert np.asarray(t.result()).min() >= 0
     srt = np.asarray(tickets[0].result())

@@ -59,7 +59,7 @@ def test_concurrent_producers_coalesce_into_one_executable_call():
             t.start()
         for t in threads:
             t.join()
-    assert count[0] == 0, "steady-state async path must not re-trace"
+    assert count() == 0, "steady-state async path must not re-trace"
     executed = svc.stats.batches - batches_before
     assert executed < N, "cross-caller requests must coalesce"
     assert executed == 1  # frozen clock: only a full batch can flush

@@ -1,0 +1,83 @@
+"""Compile the chip's hot path for a described TPU v5e, with no chip attached.
+
+The Pallas kernels at every tile width the planner sweeps, and the model-D
+cluster sort on a 4-chip mesh, go through the TPU compiler here: a kernel
+Mosaic refuses, a program that does not fit HBM or a lost collective fails
+these tests instead of a chip run. Nothing runs, so they say nothing about
+results or times.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.core.cluster_sort import _compiled_cluster_sort
+from repro.engine.planner import PALLAS_BLOCK_SWEEP
+from repro.exchange import slab_geometry
+from repro.kernels.bitonic_sort.ops import _pallas_argsort_impl, _pallas_sort_impl
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache but can never be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chip_mesh(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("x",))
+
+
+@pytest.mark.parametrize("block_n", PALLAS_BLOCK_SWEEP)
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32], ids=["int32", "float32"])
+@pytest.mark.parametrize("impl", [_pallas_sort_impl, _pallas_argsort_impl], ids=["sort", "argsort"])
+def test_pallas_kernel_compiles_for_v5e(one_chip, impl, dtype, block_n):
+    x = jax.ShapeDtypeStruct((1 << 20,), dtype, sharding=one_chip)
+    compiled = impl.lower(x, block_n=block_n, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode", ["sample", "radix"])
+def test_cluster_sort_compiles_for_four_chips(four_chip_mesh, mode):
+    n, P_ = 1 << 26, 4
+    part_buckets, n_buckets, cap = slab_geometry(mode, n // P_, P_, 2.0)
+    fn = _compiled_cluster_sort(
+        four_chip_mesh, "x", mode, cap, part_buckets, n_buckets, 3, 0, 1, "xla", None
+    )
+    x = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=NamedSharding(four_chip_mesh, P("x")))
+    compiled = fn.lower(x).compile()
+    assert "all-to-all" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    per_device = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert 0 < per_device < HBM_BYTES, per_device

@@ -189,7 +189,7 @@ def test_capacity_loop_pays_overflow_once_and_persists(tmp_path):
         for _ in range(2):
             c, d, l = one_step()
             caps.append(c); drops.append(d); losses.append(l)
-    assert count[0] == 0, f"steady-state train step re-traced: {{count[0]}}"
+    assert count() == 0, f"steady-state train step re-traced: {{count()}}"
 
     assert drops[0] > 0, "collapsed router at cf=1.0 must overflow step 0"
     assert drops[1:] == [0, 0, 0], f"overflow paid more than once: {{drops}}"

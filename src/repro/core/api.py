@@ -20,6 +20,7 @@ Key-value sorting, argsort, and the batched serving front door live in
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -27,6 +28,7 @@ import jax
 __all__ = ["sort"]
 
 
+@partial(jax.profiler.annotate_function, name="repro.sort.dispatch")
 def sort(
     x: jax.Array,
     *,

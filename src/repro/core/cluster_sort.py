@@ -68,16 +68,19 @@ def cluster_sort_local(
     (DESIGN.md §2)."""
     P_ = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
-    bucket = partitioner(local).astype(jnp.int32)
+    with jax.named_scope("repro.partition"):
+        bucket = partitioner(local).astype(jnp.int32)
     ex = partition_exchange(
         local, None, bucket, axis_name, capacity=capacity, n_buckets=n_buckets
     )
     flat = ex.recv_keys.reshape(-1)
-    sorted_slab = fast_local_sort(flat, ascending=True, impl=local_impl, block_n=block_n)
-    global_counts = jax.lax.psum(ex.counts, axis_name)  # (n_buckets,)
-    owner = (jnp.arange(n_buckets, dtype=jnp.int32) * P_) // n_buckets
-    my_count = jnp.sum(jnp.where(owner == idx, global_counts, 0)).astype(jnp.int32)
-    peak = jax.lax.pmax(jnp.max(ex.counts), axis_name)
+    with jax.named_scope("repro.local_sort"):
+        sorted_slab = fast_local_sort(flat, ascending=True, impl=local_impl, block_n=block_n)
+    with jax.named_scope("repro.counts"):
+        global_counts = jax.lax.psum(ex.counts, axis_name)  # (n_buckets,)
+        owner = (jnp.arange(n_buckets, dtype=jnp.int32) * P_) // n_buckets
+        my_count = jnp.sum(jnp.where(owner == idx, global_counts, 0)).astype(jnp.int32)
+        peak = jax.lax.pmax(jnp.max(ex.counts), axis_name)
     return sorted_slab, my_count[None], peak, ex.overflow
 
 

@@ -53,13 +53,15 @@ def shared_memory_sort(
 
     # Phase 1 — every "thread" sorts its tile (Fig 2 step: call sorting function)
     tiles = x.reshape(*lead, n_threads, tile)
-    tiles = fast_local_sort(tiles, ascending=True, impl=local_impl, block_n=block_n)
+    with jax.named_scope("repro.tile_sort"):
+        tiles = fast_local_sort(tiles, ascending=True, impl=local_impl, block_n=block_n)
     x = tiles.reshape(*lead, np2)
 
     # Phase 2 — binary merge tree (Fig 2 steps a–d), one round per doubling
     width = tile
     while width < np2:
-        x = merge_adjacent(x, width)
+        with jax.named_scope("repro.merge"):
+            x = merge_adjacent(x, width)
         width *= 2
     x = x[..., :n]
     return x if ascending else jnp.flip(x, axis=-1)

@@ -38,8 +38,10 @@ import threading
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..queue import QueueStats
@@ -379,6 +381,7 @@ class SortFrontend:
                 keep.append(p)
         self._pending = keep
 
+    @partial(jax.profiler.annotate_function, name="repro.frontend.pump")
     def pump(self) -> Optional[BatchInfo]:
         """Dispatch the single most urgent batch; None if nothing is pending.
 

@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.shared_sort import shared_memory_sort
 from .cache import CompiledCache, size_bucket
@@ -305,40 +306,41 @@ class SortService:
         bucket, dtype_name = gk[0], gk[1]
         dtype = np.dtype(dtype_name)
         bb = size_bucket(len(reqs), min_bucket=1)  # pow2 batch bucket
-        sent = _np_sentinel(dtype, largest=ascending)
-        batch = np.full((bb, bucket), sent, dtype)
-        for row, r in enumerate(reqs):
-            batch[row, : len(r)] = r
+        with TraceAnnotation("repro.service.pad"):
+            sent = _np_sentinel(dtype, largest=ascending)
+            batch = np.full((bb, bucket), sent, dtype)
+            for row, r in enumerate(reqs):
+                batch[row, : len(r)] = r
+            if kind == "sort_kv":
+                vshape, vdtype = gk[2], np.dtype(gk[3])
+                vbatch = np.zeros((bb, bucket) + vshape, vdtype)
+                for row, v in enumerate(vals):
+                    vbatch[row, : len(v)] = v
 
-        plan, key, args = self._signature(kind, gk, bb, ascending)
-
-        if kind == "sort_kv":
-            vshape, vdtype = gk[2], np.dtype(gk[3])
-            vbatch = np.zeros((bb, bucket) + vshape, vdtype)
-            for row, v in enumerate(vals):
-                vbatch[row, : len(v)] = v
-
-        with self._lock:
-            before = self.cache.misses
-            exe = self.cache.get_or_build(key, self._builder(kind, plan, ascending), args)
-            self.stats.compiles += self.cache.misses - before
-            self.stats.cache_hits += int(self.cache.misses == before)
-            self.stats.batches += 1
-            self.stats.padded_keys += bb * bucket - sum(len(r) for r in reqs)
+        with TraceAnnotation("repro.service.execute"):
+            plan, key, args = self._signature(kind, gk, bb, ascending)
+            with self._lock:
+                before = self.cache.misses
+                exe = self.cache.get_or_build(key, self._builder(kind, plan, ascending), args)
+                self.stats.compiles += self.cache.misses - before
+                self.stats.cache_hits += int(self.cache.misses == before)
+                self.stats.batches += 1
+                self.stats.padded_keys += bb * bucket - sum(len(r) for r in reqs)
+            res = jax.block_until_ready(exe(batch, vbatch) if kind == "sort_kv" else exe(batch))
 
         out: List[Any] = [None] * len(reqs)
-        if kind == "sort_kv":
-            ks, vres = exe(batch, vbatch)
-            ks, vres = np.asarray(ks), np.asarray(vres)
-            for row, r in enumerate(reqs):
-                n = len(r)
-                out[row] = (ks[row, :n], vres[row, :n])
-        else:
-            res = np.asarray(exe(batch))
-            for row, r in enumerate(reqs):
-                # sentinel padding sorts last either direction, so the
-                # leading n entries (indices < n for argsort) are the answer
-                out[row] = res[row, : len(r)]
+        with TraceAnnotation("repro.service.copy_back"):
+            if kind == "sort_kv":
+                ks, vres = np.asarray(res[0]), np.asarray(res[1])
+                for row, r in enumerate(reqs):
+                    n = len(r)
+                    out[row] = (ks[row, :n], vres[row, :n])
+            else:
+                res = np.asarray(res)
+                for row, r in enumerate(reqs):
+                    # sentinel padding sorts last either direction, so the
+                    # leading n entries (indices < n for argsort) are the answer
+                    out[row] = res[row, : len(r)]
 
         t1 = time.perf_counter()
         with self._lock:

@@ -157,78 +157,81 @@ def partition_exchange(
     B = P_ if n_buckets is None else n_buckets
     if B % P_:
         raise ValueError(f"n_buckets={B} must be a multiple of axis size {P_}")
-    if bucket_ids is None:
-        if partition == "radix":
-            bucket_ids = radix_bucket_ids(keys, B, axis_name)
-        elif partition == "sample":
-            kw = {} if oversample is None else {"oversample": oversample}
-            bucket_ids = sample_partition_ids(
-                keys, B, axis_name, stable=values is not None, **kw
-            )
-        else:
-            raise ValueError(
-                f"bucket_ids=None needs partition in ('radix', 'sample'), got {partition!r}"
-            )
-    sent = sentinel_for(keys.dtype, largest=True)
+    with jax.named_scope("repro.partition"):
+        if bucket_ids is None:
+            if partition == "radix":
+                bucket_ids = radix_bucket_ids(keys, B, axis_name)
+            elif partition == "sample":
+                kw = {} if oversample is None else {"oversample": oversample}
+                bucket_ids = sample_partition_ids(
+                    keys, B, axis_name, stable=values is not None, **kw
+                )
+            else:
+                raise ValueError(
+                    f"bucket_ids=None needs partition in ('radix', 'sample'), got {partition!r}"
+                )
+        sent = sentinel_for(keys.dtype, largest=True)
 
-    # --- group by bucket (stable: preserves arrival order per bucket) ---
-    order = _stable_argsort_by(bucket_ids)
-    sorted_bkt = bucket_ids[order]
-    counts = jnp.bincount(bucket_ids, length=B).astype(jnp.int32)
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    pos_in_bucket = jnp.arange(m, dtype=jnp.int32) - offsets[sorted_bkt]
-    valid = pos_in_bucket < C
-    slot_sorted = jnp.where(valid, sorted_bkt * C + pos_in_bucket, B * C)
+        # --- group by bucket (stable: preserves arrival order per bucket) ---
+        order = _stable_argsort_by(bucket_ids)
+        sorted_bkt = bucket_ids[order]
+        counts = jnp.bincount(bucket_ids, length=B).astype(jnp.int32)
+        offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
+        pos_in_bucket = jnp.arange(m, dtype=jnp.int32) - offsets[sorted_bkt]
+        valid = pos_in_bucket < C
+        slot_sorted = jnp.where(valid, sorted_bkt * C + pos_in_bucket, B * C)
 
-    # --- build fixed-capacity send slab (scatter, OOB slots dropped) ---
-    slab_keys = jnp.full((B * C,), sent, keys.dtype)
-    slab_keys = slab_keys.at[slot_sorted].set(keys[order], mode="drop")
+        # --- build fixed-capacity send slab (scatter, OOB slots dropped) ---
+        slab_keys = jnp.full((B * C,), sent, keys.dtype)
+        slab_keys = slab_keys.at[slot_sorted].set(keys[order], mode="drop")
 
-    def to_slab(v):
-        buf = jnp.zeros((B * C,) + v.shape[1:], v.dtype)
-        return buf.at[slot_sorted].set(v[order], mode="drop")
+        def to_slab(v):
+            buf = jnp.zeros((B * C,) + v.shape[1:], v.dtype)
+            return buf.at[slot_sorted].set(v[order], mode="drop")
 
-    slab_values = None if values is None else jax.tree.map(to_slab, values)
+        slab_values = None if values is None else jax.tree.map(to_slab, values)
 
-    # remember where each *original* element went (for combine_exchange)
-    send_slot = (
-        jnp.full((m,), -1, jnp.int32)
-        .at[order]
-        .set(jnp.where(valid, slot_sorted, -1).astype(jnp.int32))
-    )
-    # receiver-side validity mask rides along as slot ids (-1 = padding)
-    slab_src_slot = (
-        jnp.full((B * C,), -1, jnp.int32)
-        .at[slot_sorted]
-        .set(slot_sorted.astype(jnp.int32), mode="drop")
-    )
+        # remember where each *original* element went (for combine_exchange)
+        send_slot = (
+            jnp.full((m,), -1, jnp.int32)
+            .at[order]
+            .set(jnp.where(valid, slot_sorted, -1).astype(jnp.int32))
+        )
+        # receiver-side validity mask rides along as slot ids (-1 = padding)
+        slab_src_slot = (
+            jnp.full((B * C,), -1, jnp.int32)
+            .at[slot_sorted]
+            .set(slot_sorted.astype(jnp.int32), mode="drop")
+        )
 
     # --- the one MSD-radix all_to_all (paper Fig 4 arrow: master -> nodes) ---
     row = (B // P_) * C
     a2a = partial(
         jax.lax.all_to_all, axis_name=axis_name, split_axis=0, concat_axis=0, tiled=False
     )
-    recv_keys = a2a(slab_keys.reshape(P_, row))
-    recv_src_slot = a2a(slab_src_slot.reshape(P_, row))
-    if values is None:
-        recv_values = None
-    elif compress:
-        # int8 quantization is lossy and only meaningful for float payloads;
-        # integer leaves (indices, ids) ship uncompressed to stay exact
-        recv_values = jax.tree.map(
-            lambda v: (
-                _compressed_a2a(axis_name, P_, row)(v).reshape((P_, row) + v.shape[1:])
-                if jnp.issubdtype(v.dtype, jnp.floating)
-                else a2a(v.reshape((P_, row) + v.shape[1:]))
-            ),
-            slab_values,
-        )
-    else:
-        recv_values = jax.tree.map(
-            lambda v: a2a(v.reshape((P_, row) + v.shape[1:])), slab_values
-        )
+    with jax.named_scope("repro.all_to_all"):
+        recv_keys = a2a(slab_keys.reshape(P_, row))
+        recv_src_slot = a2a(slab_src_slot.reshape(P_, row))
+        if values is None:
+            recv_values = None
+        elif compress:
+            # int8 quantization is lossy and only meaningful for float payloads;
+            # integer leaves (indices, ids) ship uncompressed to stay exact
+            recv_values = jax.tree.map(
+                lambda v: (
+                    _compressed_a2a(axis_name, P_, row)(v).reshape((P_, row) + v.shape[1:])
+                    if jnp.issubdtype(v.dtype, jnp.floating)
+                    else a2a(v.reshape((P_, row) + v.shape[1:]))
+                ),
+                slab_values,
+            )
+        else:
+            recv_values = jax.tree.map(
+                lambda v: a2a(v.reshape((P_, row) + v.shape[1:])), slab_values
+            )
 
-    overflow = jax.lax.pmax(jnp.max(counts) > C, axis_name)
+    with jax.named_scope("repro.counts"):
+        overflow = jax.lax.pmax(jnp.max(counts) > C, axis_name)
     return ExchangeResult(
         recv_keys=recv_keys,
         recv_values=recv_values,
@@ -268,7 +271,8 @@ def combine_exchange(
     a2a = partial(
         jax.lax.all_to_all, axis_name=axis_name, split_axis=0, concat_axis=0, tiled=False
     )
-    returned = jax.tree.map(a2a, processed)  # (P, C, ...) back in sender layout
+    with jax.named_scope("repro.all_to_all"):
+        returned = jax.tree.map(a2a, processed)  # (P, C, ...) back in sender layout
 
     m = ex.send_slot.shape[0]
 

@@ -13,6 +13,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["run_with_capacity_retries"]
 
 # serializes the (miss-count snapshot, memoized construction) pairs inside
@@ -84,18 +86,22 @@ def run_with_capacity_retries(
     for attempt in range(max_retries + 1):
         if attempt:
             cap = min(m, cap * 2)
-        with _RECOMPILE_COUNT_LOCK:
-            misses0 = lru.cache_info().misses
-            fn = make_fn(cap)
-            fresh = lru.cache_info().misses - misses0
-        if attempt:
-            # only retry attempts count: a first-call warmup compile is the
-            # normal cost of a new config, not an overflow-forced recompile
-            recompiles += fresh
-        *outs, counts, att_peak, overflow = run_fn(fn)
-        peak = max(peak, int(att_peak))
+        with TraceAnnotation("repro.exchange.attempt"):
+            with _RECOMPILE_COUNT_LOCK:
+                misses0 = lru.cache_info().misses
+                fn = make_fn(cap)
+                fresh = lru.cache_info().misses - misses0
+            if attempt:
+                # only retry attempts count: a first-call warmup compile is the
+                # normal cost of a new config, not an overflow-forced recompile
+                recompiles += fresh
+            *outs, counts, att_peak, overflow = run_fn(fn)
+            # the host waits here for the device to finish the attempt
+            with TraceAnnotation("repro.exchange.overflow_wait"):
+                peak = max(peak, int(att_peak))
+                overflowed = bool(overflow)
         retries = attempt
-        if not bool(overflow):
+        if not overflowed:
             report(overflowed=attempt > 0)
             return outs, counts
         if cap >= m:

@@ -147,7 +147,8 @@ def compact_slabs(tree, valid, n: int, mesh, axis: str):
     >>> [int(v) for v in compact_slabs(slab, slab_valid(4, jnp.array([2]), 1), 2, mesh, "x")]
     [4, 7]
     """
-    return _compiled_compact(mesh, axis, n)(tree, valid)
+    with jax.profiler.TraceAnnotation("repro.compact.dispatch"):
+        return _compiled_compact(mesh, axis, n)(tree, valid)
 
 
 @functools.lru_cache(maxsize=64)
@@ -157,6 +158,7 @@ def _compiled_compact(mesh, axis: str, n: int):
         raise ValueError(f"n={n} must divide axis size {P_}")
     m = n // P_
 
+    @jax.named_scope("repro.compact")
     def body(tree, valid):
         C_total = valid.shape[0]
         counts = jax.lax.all_gather(jnp.sum(valid, dtype=jnp.int32), axis)

@@ -10,8 +10,9 @@ This is the paper's headline algorithm and the framework's production path:
   3. each device sorts what it received with the fast local sort (the paper's
      per-node OpenMP hybrid = our vmapped XLA/bitonic sort).
 
-The exchange machinery itself — ``partition_exchange``/``combine_exchange``,
-``slab_geometry``, the capacity-retry driver — lives in ``repro.exchange``
+The exchange machinery itself — ``sorted_runs_exchange`` (this module's
+wire: each bucket leaves as a slice of the sorted shard), ``slab_geometry``,
+the capacity-retry driver — lives in ``repro.exchange``
 (the unified adaptive exchange layer, docs/exchange.md); this module is the
 *sort* consumer of that layer, MoE dispatch (``models/moe.py``) is the other.
 The names are re-exported here for back-compat with pre-extraction callers.
@@ -27,12 +28,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro.exchange import (  # noqa: F401  (re-exported for back-compat)
     ExchangeResult,
+    bucket_counts,
     combine_exchange,
     partition_exchange,
     partition_of,
     run_with_capacity_retries,
     slab_geometry,
     slab_valid,
+    sorted_runs_exchange,
 )
 
 from .radix import make_partitioner
@@ -53,7 +56,7 @@ def cluster_sort_local(
     axis_name: str,
     *,
     capacity: int,
-    partitioner: Callable[[jax.Array], jax.Array],
+    partitioner: Callable[..., jax.Array],
     n_buckets: int,
     local_impl: str = "xla",
     block_n: Optional[int] = None,
@@ -65,23 +68,29 @@ def cluster_sort_local(
     bucket) element count — the exchange-telemetry signal capacity learning
     feeds on (repro.engine.adapt). ``n_buckets`` must be a multiple of the
     axis size; the contiguous bucket -> shard map keeps global order
-    (DESIGN.md §2)."""
+    (DESIGN.md §2).
+
+    ``partitioner(keys, sorted_keys=...)`` must be monotone in the key (every
+    ``make_partitioner`` mode is): the shard is sorted once, and each bucket
+    leaves as a slice of it (``sorted_runs_exchange``)."""
     P_ = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     with jax.named_scope("repro.partition"):
-        bucket = partitioner(local).astype(jnp.int32)
-    ex = partition_exchange(
-        local, None, bucket, axis_name, capacity=capacity, n_buckets=n_buckets
+        local_sorted = jnp.sort(local)
+        bucket = partitioner(local, sorted_keys=local_sorted).astype(jnp.int32)
+        counts = bucket_counts(bucket, n_buckets)
+    recv_keys, counts, overflow = sorted_runs_exchange(
+        local_sorted, counts, axis_name, capacity=capacity
     )
-    flat = ex.recv_keys.reshape(-1)
+    flat = recv_keys.reshape(-1)
     with jax.named_scope("repro.local_sort"):
         sorted_slab = fast_local_sort(flat, ascending=True, impl=local_impl, block_n=block_n)
     with jax.named_scope("repro.counts"):
-        global_counts = jax.lax.psum(ex.counts, axis_name)  # (n_buckets,)
+        global_counts = jax.lax.psum(counts, axis_name)  # (n_buckets,)
         owner = (jnp.arange(n_buckets, dtype=jnp.int32) * P_) // n_buckets
         my_count = jnp.sum(jnp.where(owner == idx, global_counts, 0)).astype(jnp.int32)
-        peak = jax.lax.pmax(jnp.max(ex.counts), axis_name)
-    return sorted_slab, my_count[None], peak, ex.overflow
+        peak = jax.lax.pmax(jnp.max(counts), axis_name)
+    return sorted_slab, my_count[None], peak, overflow
 
 
 @lru_cache(maxsize=256)
@@ -139,8 +148,9 @@ def cluster_sort(
     (final attempt), ``peak`` (max per-(sender, bucket) count observed),
     ``overflowed``, ``retries``, ``recompiles`` (fresh executables the
     capacity-doubling retries forced — a first-call warmup compile doesn't
-    count), and ``partition`` (the mode's family, ``"radix"``/``"sample"``)
-    — the feedback ``repro.engine.adapt`` turns into learned capacity
+    count), ``partition`` (the mode's family, ``"radix"``/``"sample"``)
+    and ``path`` (``"sorted_runs"``: the exchange that slices the sorted
+    shard) — the feedback ``repro.engine.adapt`` turns into learned capacity
     factors and, for persistently skewed radix keys, sample-mode promotion.
     """
     P_ = mesh.shape[axis]
@@ -164,5 +174,6 @@ def cluster_sort(
         lru=_compiled_cluster_sort,
         label="cluster_sort",
         partition=partition_of(mode),
+        path="sorted_runs",
     )
     return slab, slab_valid(slab.shape[0], counts, P_)

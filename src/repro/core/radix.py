@@ -73,8 +73,12 @@ def make_partitioner(
     axis_name: Optional[str] = None,
     oversample: int = 8,
     stable: bool = False,
-) -> Callable[[jax.Array], jax.Array]:
-    """Return keys -> bucket_ids for the chosen MSD mode.
+) -> Callable[..., jax.Array]:
+    """Return ``(keys, sorted_keys=None) -> bucket_ids`` for the chosen MSD mode.
+
+    ``sorted_keys``, if given, is the same shard sorted ascending; the
+    ``splitters`` mode takes its sample from it instead of sorting the shard
+    itself, and the other modes ignore it.
 
     ``stable`` only affects ``sample`` mode: it selects arrival-order tie ids
     so a stable kv sort stays stable with bucket boundaries inside tie runs
@@ -83,19 +87,23 @@ def make_partitioner(
     if mode == "decimal":
         if n_buckets != 10:
             raise ValueError("decimal MSD implies exactly 10 buckets (paper §3.4)")
-        return lambda k: decimal_msd_bucket(k, digits=digits)
+        return lambda k, sorted_keys=None: decimal_msd_bucket(k, digits=digits)
     if mode == "range":
-        return lambda k: range_bucket(k, n_buckets=n_buckets, lo=lo, hi=hi)
+        return lambda k, sorted_keys=None: range_bucket(
+            k, n_buckets=n_buckets, lo=lo, hi=hi
+        )
     if mode == "radix":
         if axis_name is None:
             raise ValueError("radix mode needs the mesh axis name")
-        return lambda k: radix_bucket_ids(k, n_buckets, axis_name)
+        return lambda k, sorted_keys=None: radix_bucket_ids(k, n_buckets, axis_name)
     if mode == "splitters":
         if axis_name is None:
             raise ValueError("splitters mode needs the mesh axis name")
 
-        def part(k):
-            spl = choose_splitters(k, n_buckets, axis_name, oversample=oversample)
+        def part(k, sorted_keys=None):
+            spl = choose_splitters(
+                k, n_buckets, axis_name, oversample=oversample, sorted_keys=sorted_keys
+            )
             return splitter_bucket(k, spl)
 
         return part
@@ -105,7 +113,7 @@ def make_partitioner(
         # choose_splitters keeps its historic default; the composite sample
         # partition wants the larger DEFAULT_OVERSAMPLE unless overridden
         os_ = max(oversample, DEFAULT_OVERSAMPLE)
-        return lambda k: sample_partition_ids(
+        return lambda k, sorted_keys=None: sample_partition_ids(
             k, n_buckets, axis_name, oversample=os_, stable=stable
         )
     raise ValueError(f"unknown partitioner mode {mode!r}")

@@ -209,6 +209,7 @@ def cluster_sort_kv(
         lru=_compiled_cluster_kv,
         label="cluster_sort_kv",
         partition=partition_of(mode),
+        path="scatter",
     )
     return slab_k, slab_v, slab_valid(slab_k.shape[0], counts, P_)
 

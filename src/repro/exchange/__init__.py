@@ -19,7 +19,8 @@ slabs      : slab/capacity math — ``sentinel_for``, ``slab_capacity``,
              ``slab_valid`` and ``compact_slabs`` (slab -> dense result)
 collective : the wire — ``partition_exchange`` / ``combine_exchange`` /
              ``ExchangeResult`` (single all_to_all each way, optional int8
-             compression)
+             compression); ``sorted_runs_exchange`` + ``bucket_counts``, the
+             keys-only sort's slicing twin
 retry      : ``run_with_capacity_retries`` — the capacity-doubling retry
              driver with per-attempt recompile accounting
 telemetry  : ``ExchangeObservation`` / ``ExchangeTelemetry`` — the ledger
@@ -33,7 +34,13 @@ partition  : the bucket-assignment policy — ``radix_bucket_ids`` (auto-ranged
 See docs/exchange.md for the layer's design and the model-D-sort vs
 MoE-dispatch comparison.
 """
-from .collective import ExchangeResult, combine_exchange, partition_exchange
+from .collective import (
+    ExchangeResult,
+    bucket_counts,
+    combine_exchange,
+    partition_exchange,
+    sorted_runs_exchange,
+)
 from .partition import (
     DEFAULT_OVERSAMPLE,
     PARTITION_MODES,
@@ -62,6 +69,7 @@ __all__ = [
     "ExchangeObservation",
     "ExchangeResult",
     "ExchangeTelemetry",
+    "bucket_counts",
     "choose_splitters",
     "combine_exchange",
     "compact_slabs",
@@ -75,6 +83,7 @@ __all__ = [
     "slab_capacity",
     "slab_geometry",
     "slab_valid",
+    "sorted_runs_exchange",
     "splitter_bucket",
     "splitters_from_sample",
 ]

@@ -2,9 +2,15 @@
 
 ``partition_exchange`` ships every element to the shard owning its bucket;
 ``combine_exchange`` is the exact inverse (MoE's return trip).  Buckets are
-generic: model-D sort passes radix digits / splitter ranks, MoE dispatch
+generic: key-value sorts pass radix digits / splitter ranks, MoE dispatch
 passes expert ids — same slabs, same overflow semantics, same telemetry
 signal (``ExchangeResult.counts`` / ``.overflow``).
+
+``sorted_runs_exchange`` is the keys-only model-D sort's wire.  Its buckets
+are monotone in the key, so in a sorted shard each bucket is one contiguous
+run, and each send-slab row is a slice of that run: no argsort, gather or
+scatter (docs/exchange.md, "Two paths").  ``bucket_counts`` gives it the
+per-bucket counts without a scatter.
 
 SPMD adaptation (DESIGN.md §2): MPI's variable-length messages become
 fixed-capacity slabs of ``capacity`` elements per (src, dst) pair, padded
@@ -24,7 +30,13 @@ import jax.numpy as jnp
 from .partition import radix_bucket_ids, sample_partition_ids
 from .slabs import sentinel_for
 
-__all__ = ["ExchangeResult", "combine_exchange", "partition_exchange"]
+__all__ = [
+    "ExchangeResult",
+    "bucket_counts",
+    "combine_exchange",
+    "partition_exchange",
+    "sorted_runs_exchange",
+]
 
 
 @dataclass
@@ -240,6 +252,87 @@ def partition_exchange(
         counts=counts,
         overflow=overflow,
     )
+
+
+def bucket_counts(bucket_ids: jax.Array, n_buckets: int) -> jax.Array:
+    """``jnp.bincount(bucket_ids, length=n_buckets)`` as compare-and-sum
+    reductions: ``n_buckets`` is static and small, and a scatter-add of m
+    ones is the slow way to count on a TPU.  Ids outside
+    ``[0, n_buckets)`` are counted nowhere.
+
+    >>> import jax.numpy as jnp
+    >>> [int(c) for c in bucket_counts(jnp.array([0, 2, 2, 3, 2]), 4)]
+    [1, 0, 3, 1]
+    """
+    ids = jnp.arange(n_buckets, dtype=bucket_ids.dtype)
+    return jnp.sum(bucket_ids[None, :] == ids[:, None], axis=1, dtype=jnp.int32)
+
+
+def sorted_runs_exchange(
+    sorted_keys: jax.Array,
+    counts: jax.Array,
+    axis_name: str,
+    *,
+    capacity: int,
+):
+    """Ship a sorted shard's bucket runs to their shards (call inside shard_map).
+
+    The keys-only twin of ``partition_exchange``.  ``sorted_keys`` is this
+    shard's keys in ascending order, and ``counts`` (``(n_buckets,)``
+    int32) how many of them fall in each bucket, for a bucket map that is
+    monotone in the key (``k1 <= k2`` implies ``bucket(k1) <=
+    bucket(k2)``).  Then bucket b's keys are, as a multiset, entries
+    ``[start_b, start_b + counts[b])`` of ``sorted_keys`` with ``start_b =
+    counts[:b].sum()``, even where a bucket boundary splits a run of equal
+    keys; and slab row b is a static-length slice there.  No index array of
+    length m is built: no gather and no scatter.  Within a bucket the keys
+    arrive in key order, not arrival order, so only a keys-only sort (where
+    equal keys cannot be told apart) may use it.
+
+    Buckets map to shards contiguously, as in ``partition_exchange``, and
+    ``capacity`` is per (sender, bucket).  Returns ``(recv_keys, counts,
+    overflow)``: ``recv_keys`` ``(P, B_loc * capacity)`` in
+    ``partition_exchange``'s slab layout, sentinel-padded, and ``overflow``
+    true on every shard if any (sender, bucket) count exceeded
+    ``capacity`` (the row then holds its first ``capacity`` keys).
+
+    >>> import jax, jax.numpy as jnp, repro
+    >>> from jax.sharding import PartitionSpec as P
+    >>> mesh = jax.make_mesh((jax.device_count(),), ("x",))
+    >>> def body(k):  # bucket id == destination shard, which is monotone
+    ...     k = jnp.sort(k)
+    ...     counts = bucket_counts(k * jax.device_count() // 16, jax.device_count())
+    ...     recv, _, ovf = sorted_runs_exchange(k, counts, "x", capacity=16)
+    ...     return recv.reshape(-1), ovf
+    >>> recv, ovf = jax.jit(jax.shard_map(
+    ...     body, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P())))(jnp.arange(16))
+    >>> int((recv < 16).sum()), bool(ovf)   # all 16 keys arrived, no overflow
+    (16, False)
+    """
+    P_ = jax.lax.axis_size(axis_name)
+    C = capacity
+    B = counts.shape[0]
+    if B % P_:
+        raise ValueError(f"n_buckets={B} must be a multiple of axis size {P_}")
+    with jax.named_scope("repro.partition"):
+        sent = sentinel_for(sorted_keys.dtype, largest=True)
+        # C sentinels behind the shard, so no slice is clamped back into it
+        padded = jnp.concatenate([sorted_keys, jnp.full((C,), sent, sorted_keys.dtype)])
+        starts = jnp.cumsum(counts) - counts
+        slot = jnp.arange(C, dtype=jnp.int32)
+        slab_keys = jnp.stack([
+            jnp.where(slot < counts[b], jax.lax.dynamic_slice(padded, (starts[b],), (C,)), sent)
+            for b in range(B)
+        ])
+
+    row = (B // P_) * C
+    with jax.named_scope("repro.all_to_all"):
+        recv_keys = jax.lax.all_to_all(
+            slab_keys.reshape(P_, row), axis_name, 0, 0, tiled=False
+        )
+    with jax.named_scope("repro.counts"):
+        overflow = jax.lax.pmax(jnp.max(counts) > C, axis_name)
+    return recv_keys, counts, overflow
 
 
 def combine_exchange(

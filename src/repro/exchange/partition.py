@@ -161,12 +161,26 @@ def splitters_from_sample(
     [7]
     """
     flat = jnp.sort(jnp.asarray(sample).reshape(-1))
-    total = flat.shape[0]
-    q = (jnp.arange(1, n_buckets) * total) // n_buckets
-    spl = flat[q]
+    spl = _quantiles(flat, n_buckets)
     if unique:
         return jnp.asarray(np.unique(np.asarray(spl)))
     return spl
+
+
+def _strided(x: jax.Array, stride: int, s: int) -> jax.Array:
+    """``x[..., ::stride][..., :s]`` as one strided slice (jnp's strided
+    indexing lowers to a gather)."""
+    return jax.lax.slice_in_dim(x, 0, (s - 1) * stride + 1, stride, axis=-1)
+
+
+def _quantiles(flat: jax.Array, n_buckets: int) -> jax.Array:
+    """Entries ``(j * len) // n_buckets`` for j in 1..B-1 of a sorted array.
+
+    The positions are static, so each is a plain slice: no gather.
+    """
+    total = flat.shape[0]
+    picks = [flat[(j * total) // n_buckets] for j in range(1, n_buckets)]
+    return jnp.stack(picks) if picks else flat[:0]
 
 
 def choose_splitters(
@@ -175,13 +189,16 @@ def choose_splitters(
     axis_name: str,
     *,
     oversample: int = 8,
+    sorted_keys: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Distributed quantile-splitter selection (samplesort), inside shard_map.
 
     Every device contributes ``oversample * n_buckets`` strided samples of
     its *sorted* shard; the all-gathered sample is sorted and B-1 quantiles
     become the splitters.  One small all_gather — negligible next to the
-    data exchange.
+    data exchange.  ``sorted_keys``, if given, is ``local_keys`` already
+    sorted ascending (the keys-only model-D sort has it at hand), and the
+    shard is not sorted again.
 
     >>> import jax, jax.numpy as jnp, repro
     >>> from jax.sharding import PartitionSpec as P
@@ -196,8 +213,8 @@ def choose_splitters(
     m = local_keys.shape[-1]
     s = min(m, oversample * n_buckets)
     stride = max(1, m // s)
-    local_sorted = jnp.sort(local_keys, axis=-1)
-    sample = local_sorted[..., ::stride][..., :s]
+    local_sorted = jnp.sort(local_keys, axis=-1) if sorted_keys is None else sorted_keys
+    sample = _strided(local_sorted, stride, s)
     gathered = jax.lax.all_gather(sample, axis_name)  # (P, s)
     return splitters_from_sample(gathered, n_buckets)
 
@@ -213,16 +230,15 @@ def _composite_splitters(
     m = local_keys.shape[-1]
     s = min(m, oversample * n_buckets)
     stride = max(1, m // s)
-    order = jnp.argsort(local_keys, stable=True)
-    sk = local_keys[order][::stride][:s]
-    sid = gid[order][::stride][:s]
+    # ids ride through the sort as a second operand: the same order as a
+    # stable argsort, with no gather through it
+    sk, sid = jax.lax.sort((local_keys, gid), num_keys=1, is_stable=True)
+    sk, sid = _strided(sk, stride, s), _strided(sid, stride, s)
     gk = jax.lax.all_gather(sk, axis_name).reshape(-1)
     gi = jax.lax.all_gather(sid, axis_name).reshape(-1)
-    pos = jnp.lexsort((gi, gk))  # composite order: key major, id minor
-    gk, gi = gk[pos], gi[pos]
-    total = gk.shape[0]
-    q = (jnp.arange(1, n_buckets) * total) // n_buckets
-    return gk[q], gi[q]
+    # composite order: key major, id minor (ids are unique)
+    gk, gi = jax.lax.sort((gk, gi), num_keys=2)
+    return _quantiles(gk, n_buckets), _quantiles(gi, n_buckets)
 
 
 def sample_partition_ids(

@@ -37,6 +37,7 @@ def run_with_capacity_retries(
     label: str,
     strict: bool = True,
     partition: Optional[str] = None,
+    path: Optional[str] = None,
 ):
     """Shared capacity-doubling retry driver for exchange-based paths.
 
@@ -52,7 +53,9 @@ def run_with_capacity_retries(
     attempt's outputs with the overflow already reported.  Either way the
     final attempt's telemetry (peak per-(sender, bucket) count, overflow/
     retry/recompile events) is reported through ``telemetry`` — the feedback
-    ``repro.engine.adapt`` turns into learned capacity factors.
+    ``repro.engine.adapt`` turns into learned capacity factors — tagged with
+    the caller's ``partition`` family and exchange ``path``
+    (``"sorted_runs"`` or ``"scatter"``, ``ExchangeObservation.path``).
 
     >>> import jax.numpy as jnp
     >>> from functools import lru_cache
@@ -81,6 +84,7 @@ def run_with_capacity_retries(
                 retries=retries,
                 recompiles=recompiles,
                 partition=partition,
+                path=path,
             )
 
     for attempt in range(max_retries + 1):

@@ -40,8 +40,8 @@ class ExchangeObservation:
     3.0
     >>> obs.dropped, obs.dropped_averted   # sorts never drop; MoE may
     (0, 0)
-    >>> obs.partition is None              # caller didn't tag the family
-    True
+    >>> obs.partition is None, obs.path    # caller didn't tag family or path
+    (True, None)
     """
 
     m: int                  # per-shard element count
@@ -59,6 +59,11 @@ class ExchangeObservation:
     #                                  bucket ids ("radix"/"sample"); None for
     #                                  callers outside the policy (e.g. MoE,
     #                                  where the router is the partitioner)
+    path: Optional[str] = None  # exchange that built the send slabs:
+    #                             "sorted_runs" (keys-only sort: slices of
+    #                             the sorted shard) or "scatter" (argsort and
+    #                             scatter: key-value sorts, MoE); None for
+    #                             callers that do not say
 
     def required_factor(self) -> float:
         """Smallest ``capacity_factor`` that fits ``peak`` without overflow."""
@@ -96,6 +101,11 @@ class ExchangeTelemetry:
     1
     >>> led.overflow_events, led.total_retries, led.total_dropped
     (1, 1, 0)
+    >>> led.record("4096|int32|local/cpu", ExchangeObservation(
+    ...     m=128, part_buckets=8, capacity=32, peak=16,
+    ...     overflowed=False, retries=0, path="sorted_runs"))
+    >>> led.path_calls                     # calls per exchange path, where told
+    {'sorted_runs': 1}
     """
 
     def __init__(self, window: int = 256):
@@ -109,6 +119,7 @@ class ExchangeTelemetry:
         self.total_recompiles = 0
         self.total_dropped = 0
         self.total_dropped_averted = 0
+        self.path_calls: Dict[str, int] = {}
 
     def subscribe(self, fn) -> None:
         """Register ``fn(key, obs)`` to run after every ``record``.
@@ -130,6 +141,8 @@ class ExchangeTelemetry:
             self.total_recompiles += obs.recompiles
             self.total_dropped += obs.dropped
             self.total_dropped_averted += obs.dropped_averted
+            if obs.path is not None:
+                self.path_calls[obs.path] = self.path_calls.get(obs.path, 0) + 1
             subscribers = list(self._subscribers)
         for fn in subscribers:
             fn(key, obs)

@@ -420,6 +420,7 @@ def moe_apply_adaptive(
         lru=_compiled_moe_replicated,
         label="moe_apply_adaptive",
         strict=False,
+        path="scatter",
     )
     return y, aux, counts
 
@@ -540,6 +541,7 @@ def moe_apply_local_adaptive(
         lru=_compiled_moe_local,
         label="moe_apply_local_adaptive",
         strict=False,
+        path="scatter",
     )
     return y, aux, counts
 

@@ -118,6 +118,7 @@ class MoECapacityController:
             retries=0,
             recompiles=0,
             dropped=dropped,
+            path="scatter",
         )
         self.planner.observe_exchange(
             self.key, obs, default=self.cfg.capacity_factor

@@ -11,6 +11,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -65,15 +66,33 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, impl, dtype, block_n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+CLUSTER_N = 1 << 26
+
+
+@pytest.fixture(scope="module")
+def compiled_cluster(four_chip_mesh):
+    """The four-chip model-D program at ``CLUSTER_N`` keys, compiled once per mode."""
+    done = {}
+
+    def compile_mode(mode):
+        if mode not in done:
+            P_ = 4
+            part_buckets, n_buckets, cap = slab_geometry(mode, CLUSTER_N // P_, P_, 2.0)
+            fn = _compiled_cluster_sort(
+                four_chip_mesh, "x", mode, cap, part_buckets, n_buckets, 3, 0, 1, "xla", None
+            )
+            x = jax.ShapeDtypeStruct(
+                (CLUSTER_N,), jnp.int32, sharding=NamedSharding(four_chip_mesh, P("x"))
+            )
+            done[mode] = fn.lower(x).compile()
+        return done[mode]
+
+    return compile_mode
+
+
 @pytest.mark.parametrize("mode", ["sample", "radix"])
-def test_cluster_sort_compiles_for_four_chips(four_chip_mesh, mode):
-    n, P_ = 1 << 26, 4
-    part_buckets, n_buckets, cap = slab_geometry(mode, n // P_, P_, 2.0)
-    fn = _compiled_cluster_sort(
-        four_chip_mesh, "x", mode, cap, part_buckets, n_buckets, 3, 0, 1, "xla", None
-    )
-    x = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=NamedSharding(four_chip_mesh, P("x")))
-    compiled = fn.lower(x).compile()
+def test_cluster_sort_compiles_for_four_chips(compiled_cluster, mode):
+    compiled = compiled_cluster(mode)
     assert "all-to-all" in compiled.as_text()
     mem = compiled.memory_analysis()
     per_device = (
@@ -81,3 +100,31 @@ def test_cluster_sort_compiles_for_four_chips(four_chip_mesh, mode):
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
     assert 0 < per_device < HBM_BYTES, per_device
+
+
+_HLO_OP = re.compile(r"= (?P<shape>.*?) (?P<op>[a-z][a-z-]*)\((?P<rest>.*)$")
+
+
+def _ops_under(hlo: str, scope: str):
+    """(opcode, result shape) of every HLO instruction whose ``op_name`` lies
+    under ``scope``, fused computations included."""
+    out = []
+    for line in hlo.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = _HLO_OP.search(line)
+        if name and op and scope in name.group(1).split("/"):
+            out.append((op.group("op"), op.group("shape")))
+    return out
+
+
+@pytest.mark.parametrize("mode,max_sorts", [("splitters", 1), ("sample", 2)])
+def test_cluster_sort_partition_is_gather_free(compiled_cluster, mode, max_sorts):
+    """The keys-only partition sorts the shard and slices it: no gather and
+    no scatter under ``repro.partition``, and at most one sort of a shard's
+    m keys (two in sample mode, whose composite splitters sort (key, id))."""
+    ops = _ops_under(compiled_cluster(mode).as_text(), "repro.partition")
+    assert ops, "no instruction carries the repro.partition scope"
+    assert not [o for o in ops if o[0] in ("gather", "scatter")], ops
+    m = CLUSTER_N // 4
+    sorts = [o for o in ops if o[0] == "sort" and f"[{m}]" in o[1]]
+    assert len(sorts) <= max_sorts, sorts

@@ -8,8 +8,11 @@ out of the slab layout: within a bucket, receive order is (sender shard, slot
 in sender's slab) which *is* global arrival order, so a stable local argsort
 of the received slab reproduces ``np.argsort(kind='stable')`` exactly.
 
-Single-device calls (``mesh=None``) use a stable XLA argsort + gather; the
-distributed path requires 1-D keys with length divisible by the axis size.
+Single-device calls (``mesh=None``) run a jitted stable argsort under
+``jax.named_scope("repro.kv_order")``, then one jitted gather per leaf (keys
+and each payload leaf) under ``repro.kv_permute``; the host span
+``repro.kv.dispatch`` covers each ``sort_kv`` call. The distributed path
+requires 1-D keys with length divisible by the axis size.
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ def _rev_key(keys: jax.Array) -> jax.Array:
     return -keys
 
 
+@partial(jax.jit, static_argnames=("ascending", "impl", "block_n"))
 def _order_keys(
     keys: jax.Array,
     *,
@@ -56,27 +60,46 @@ def _order_keys(
     stable (key, rank) network — identical permutation, VMEM-tiled execution
     (but unspecified output for NaN keys, which only 'xla' totally orders).
     """
-    k = keys if ascending else _rev_key(keys)
-    if impl == "pallas":
-        from repro.kernels.bitonic_sort.ops import (
-            DEFAULT_BLOCK_N,
-            pallas_argsort,
-            vmap_last_axis,
-        )
-
-        return vmap_last_axis(
-            partial(pallas_argsort, block_n=block_n or DEFAULT_BLOCK_N), k
-        )
-    if impl != "xla":
+    if impl not in ("xla", "pallas"):
         raise ValueError(f"argsort impl must be 'xla' or 'pallas', got {impl!r}")
-    return jnp.argsort(k, axis=-1, stable=True)
+    with jax.named_scope("repro.kv_order"):
+        k = keys if ascending else _rev_key(keys)
+        if impl == "pallas":
+            from repro.kernels.bitonic_sort.ops import (
+                DEFAULT_BLOCK_N,
+                pallas_argsort,
+                vmap_last_axis,
+            )
+
+            return vmap_last_axis(
+                partial(pallas_argsort, block_n=block_n or DEFAULT_BLOCK_N), k
+            )
+        return jnp.argsort(k, axis=-1, stable=True)
 
 
+@jax.jit
 def _gather_last(v: jax.Array, order: jax.Array) -> jax.Array:
-    """Index ``v`` (shaped like keys + optional trailing dims) by ``order``."""
-    extra = v.ndim - order.ndim
-    idx = order.reshape(order.shape + (1,) * extra)
-    return jnp.take_along_axis(v, idx, axis=order.ndim - 1)
+    """Index ``v`` (shaped like keys + optional trailing dims) by ``order``.
+
+    A program of its own per leaf when called outside a jit: alone, the
+    compiler brings the gathered array into the chip's fast memory. With all
+    five gathers of a four-column record sort in one program it gathered
+    three from HBM, and a v5e took 1.09 s a call at 2^24 records, not 0.76."""
+    with jax.named_scope("repro.kv_permute"):
+        extra = v.ndim - order.ndim
+        idx = order.reshape(order.shape + (1,) * extra)
+        return jnp.take_along_axis(v, idx, axis=order.ndim - 1)
+
+
+def _sort_records(keys: jax.Array, values: Any, *, ascending: bool,
+                  impl: str = "xla", block_n: Optional[int] = None):
+    """One-device record sort: the stable order of ``keys``, then the keys
+    and every leaf of ``values`` gathered by it. ``sort_kv`` calls it on one
+    device; the service's ``sort_kv`` kind traces it into one program."""
+    order = _order_keys(keys, ascending=ascending, impl=impl, block_n=block_n)
+    return _gather_last(keys, order), jax.tree.map(
+        lambda v: _gather_last(v, order), values
+    )
 
 
 # ------------------------------------------------------------- cluster path ---
@@ -215,6 +238,7 @@ def cluster_sort_kv(
 
 
 # ---------------------------------------------------------------- front API ---
+@partial(jax.profiler.annotate_function, name="repro.kv.dispatch")
 def sort_kv(
     keys: jax.Array,
     values: Any,
@@ -245,10 +269,7 @@ def sort_kv(
     [1, 2, 0]
     """
     if mesh is None:
-        order = _order_keys(keys, ascending=ascending, impl=impl, block_n=block_n)
-        return _gather_last(keys, order), jax.tree.map(
-            lambda v: _gather_last(v, order), values
-        )
+        return _sort_records(keys, values, ascending=ascending, impl=impl, block_n=block_n)
     if axis is None:
         raise ValueError("sort_kv with mesh= requires axis=")
     if not ascending:

@@ -21,6 +21,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -30,7 +31,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.shared_sort import shared_memory_sort
 from .cache import CompiledCache, size_bucket
-from .kv import _gather_last, _order_keys
+from .kv import _order_keys, _sort_records
 from .planner import Planner, SortPlan, default_planner
 
 __all__ = ["SortService", "ServiceStats"]
@@ -171,12 +172,9 @@ class SortService:
                 )
         else:  # sort_kv
             def build():
-                def f(xb, vb):
-                    order = _order_keys(
-                        xb, ascending=ascending, impl=impl, block_n=block_n
-                    )
-                    return _gather_last(xb, order), _gather_last(vb, order)
-                return f
+                return partial(
+                    _sort_records, ascending=ascending, impl=impl, block_n=block_n
+                )
         return build
 
     # ---------------------------------------------------------- validation ---

@@ -141,6 +141,14 @@ def compact_slabs(tree, valid, n: int, mesh, axis: str):
     shapes, so it runs the same on Auto and Explicit mesh axes (an eager
     boolean-mask index does not).
 
+    Source shard p's valid prefix is one run of dense positions, so the
+    ``m = n / P_`` positions a shard keeps are at most ``P_`` contiguous
+    pieces, one from each source's prefix.  Each shard all-gathers the slab,
+    takes one length-``m`` ``dynamic_slice`` per source at the offset where
+    that source's prefix meets its range, and picks between the ``P_``
+    windows by comparing each position with the prefix starts: no index
+    array, gather or scatter.  Trailing dims of a leaf ride along.
+
     >>> import jax, jax.numpy as jnp
     >>> mesh = jax.make_mesh((1,), ("x",))
     >>> slab = jnp.array([4, 7, 0, 0])
@@ -162,13 +170,29 @@ def _compiled_compact(mesh, axis: str, n: int):
     def body(tree, valid):
         C_total = valid.shape[0]
         counts = jax.lax.all_gather(jnp.sum(valid, dtype=jnp.int32), axis)
-        ends = jnp.cumsum(counts)
-        g = jax.lax.axis_index(axis) * m + jnp.arange(m, dtype=jnp.int32)
-        owner = jnp.searchsorted(ends, g, side="right")
-        src = owner * C_total + g - (ends[owner] - counts[owner])
-        return jax.tree.map(
-            lambda a: jax.lax.all_gather(a, axis, tiled=True)[src], tree
-        )
+        starts = jnp.cumsum(counts) - counts
+        lo = jax.lax.axis_index(axis) * m
+        g = lo + jnp.arange(m, dtype=jnp.int32)
+        # Dense position g of source p sits at slot p * C_total + g - starts[p]
+        # of the gathered slab.  Where p owns any of [lo, lo + m) its window
+        # lies inside the slab (starts[p] <= p * C_total, and the range's end
+        # is at most n, which is starts[p] plus what shards p.. hold), so
+        # dynamic_slice never clamps, and so never shifts, a piece in use.
+        offsets = [lo + p * C_total - starts[p] for p in range(P_)]
+        # the owner of g is the last source whose prefix starts at or before
+        # g; an empty source ties with the next one, which then wins
+        later = [g >= starts[p] for p in range(1, P_)]
+
+        def leaf(a):
+            whole = jax.lax.all_gather(a, axis, tiled=True)
+            out = jax.lax.dynamic_slice_in_dim(whole, offsets[0], m)
+            for p in range(1, P_):
+                piece = jax.lax.dynamic_slice_in_dim(whole, offsets[p], m)
+                pick = later[p - 1].reshape((m,) + (1,) * (a.ndim - 1))
+                out = jnp.where(pick, piece, out)
+            return out
+
+        return jax.tree.map(leaf, tree)
 
     return jax.jit(
         jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(axis))

@@ -1,10 +1,10 @@
 """Compile the chip's hot path for a described TPU v5e, with no chip attached.
 
 The Pallas kernels at every tile width the planner sweeps, and the model-D
-cluster sort on a 4-chip mesh, go through the TPU compiler here: a kernel
-Mosaic refuses, a program that does not fit HBM or a lost collective fails
-these tests instead of a chip run. Nothing runs, so they say nothing about
-results or times.
+cluster sort and its dense compaction on a 4-chip mesh, go through the TPU
+compiler here: a kernel Mosaic refuses, a program that does not fit HBM or a
+lost collective fails these tests instead of a chip run. Nothing runs, so
+they say nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro.core.cluster_sort import _compiled_cluster_sort
 from repro.engine.planner import PALLAS_BLOCK_SWEEP
 from repro.exchange import slab_geometry
+from repro.exchange.slabs import _compiled_compact
 from repro.kernels.bitonic_sort.ops import _pallas_argsort_impl, _pallas_sort_impl
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -128,3 +129,25 @@ def test_cluster_sort_partition_is_gather_free(compiled_cluster, mode, max_sorts
     m = CLUSTER_N // 4
     sorts = [o for o in ops if o[0] == "sort" and f"[{m}]" in o[1]]
     assert len(sorts) <= max_sorts, sorts
+
+
+def test_compaction_is_gather_free_and_fits_hbm(four_chip_mesh):
+    """The dense compaction at ``sort.zipf.4chip``'s shapes (n = 2^28 keys
+    from a 2^29-slot slab, 2^27 slots a chip) builds each chip's range from
+    slices of the gathered slab: no gather and no scatter under
+    ``repro.compact``, and the program fits one chip's HBM."""
+    n, total = 1 << 28, 1 << 29
+    sharding = NamedSharding(four_chip_mesh, P("x"))
+    slab = jax.ShapeDtypeStruct((total,), jnp.int32, sharding=sharding)
+    valid = jax.ShapeDtypeStruct((total,), jnp.bool_, sharding=sharding)
+    compiled = _compiled_compact(four_chip_mesh, "x", n).lower(slab, valid).compile()
+    ops = _ops_under(compiled.as_text(), "repro.compact")
+    assert ops, "no instruction carries the repro.compact scope"
+    assert not [o for o in ops if o[0] in ("gather", "scatter")], ops
+    assert [o for o in ops if o[0] == "dynamic-slice"], ops
+    mem = compiled.memory_analysis()
+    per_device = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert 0 < per_device < HBM_BYTES, per_device

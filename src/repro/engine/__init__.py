@@ -4,24 +4,22 @@ planner  : SortPlan + autotuner + persistent JSON plan cache; candidate
            sweep covers local_impl='pallas' with a tuned block_n grid;
            folds learned capacity factors into cluster plans
 adapt    : closed-loop tuning — ExchangeTelemetry + CapacityLearner turn
-           observed model-D overflow into learned capacity factors, and
-           DelayController adapts the async flush window to arrival rate
+           observed model-D overflow into learned capacity factors;
+           ManualClock, the deterministic clock tests inject
 cache    : compiled-executable cache with pow2 shape bucketing
 kv       : sort_kv / argsort / sort_pairs / topk — records, not just keys
            (impl='pallas' runs the kernels' stable (key, rank) network)
 service  : SortService — ragged batches in, zero-recompile sorts out
-queue    : AsyncSortService — async request queue that micro-batches
-           individual submit_async calls across callers (docs/serving.md)
-frontend : SLO-aware multi-tenant serving front end — AOT warmup of the
-           whole plan-cache executable ladder, per-tenant weighted
-           admission with EDF dispatch and reject-with-reason load shed,
-           and a reproducible open-loop load harness (docs/serving.md)
+frontend : SortFrontend, the one queued front door over SortService —
+           cross-caller micro-batching, AOT warmup of the whole plan-cache
+           executable ladder, per-tenant weighted admission with EDF
+           dispatch and reject-with-reason load shed, and a reproducible
+           open-loop load simulation (docs/serving.md)
 
 See docs/architecture.md for the layer map and request lifecycle.
 """
 from .adapt import (
     CapacityLearner,
-    DelayController,
     ExchangeObservation,
     ExchangeTelemetry,
     LearnedCapacity,
@@ -51,12 +49,10 @@ from .frontend import (
     run_load,
     warmup,
 )
-from .queue import AsyncSortService, QueueStats
 from .service import ServiceStats, SortService
 
 __all__ = [
     "CapacityLearner",
-    "DelayController",
     "ExchangeObservation",
     "ExchangeTelemetry",
     "LearnedCapacity",
@@ -79,8 +75,6 @@ __all__ = [
     "run_plan",
     "ServiceStats",
     "SortService",
-    "AsyncSortService",
-    "QueueStats",
     "LoadReport",
     "ShedError",
     "SortFrontend",

@@ -1,7 +1,5 @@
 """Closed-loop adaptive tuning — learn knobs from observed runtime behaviour.
 
-Two feedback loops, both deterministic and clock-injectable:
-
 **Capacity learning** (model D *and* MoE dispatch).  Without it, every
 exchange call re-learns slab capacity the hard way: overflow, double
 ``capacity_factor``, recompile, retry (or, on the MoE fixed path, drop
@@ -17,25 +15,15 @@ factors through its JSON plan cache, so a restarted serving process sizes
 slabs (and expert token buffers) right on the **first** compile — zero
 overflow-retry recompiles in steady state.
 
-**Adaptive flush window** (async serving).  ``DelayController`` owns the
-``AsyncSortService`` coalescing deadline: it tracks rolling arrival rate
-and per-flush fill ratio, shrinks the window when batches fill before the
-deadline (the queue is adding latency for no extra fill), and grows it when
-deadline flushes run sparse (a longer wait would amortize better) — always
-within ``[min_delay_ms, max_delay_ms]``.
-
-Every decision consumes an injectable monotonic ``clock`` (``ManualClock``
-for tests), so adaptation is reproducible step by step — no wall-clock
-dependence anywhere in the loop.  See docs/serving.md and
+The learner is a pure function of its observations, so adaptation replays
+step by step.  ``ManualClock`` is the deterministic clock the serving
+frontend's tests inject wherever a ``clock=`` is accepted.  See
 docs/plan-cache.md for how the pieces wire together.
 """
 from __future__ import annotations
 
-import threading
-import time
-from collections import deque
 from dataclasses import asdict, dataclass
-from typing import Callable, Optional
+from typing import Optional
 
 from repro.exchange import ExchangeObservation, ExchangeTelemetry  # noqa: F401
 # ^ the observation schema + ledger live in the unified exchange layer now
@@ -44,7 +32,6 @@ from repro.exchange import ExchangeObservation, ExchangeTelemetry  # noqa: F401
 
 __all__ = [
     "CapacityLearner",
-    "DelayController",
     "ExchangeObservation",
     "ExchangeTelemetry",
     "LearnedCapacity",
@@ -362,90 +349,3 @@ class CapacityLearner:
         """True once the calm streak has outlasted this generation's
         probation threshold."""
         return streak >= self.demote_threshold(demotions)
-
-
-class DelayController:
-    """Adaptive coalescing window for ``AsyncSortService``.
-
-    Owns the effective ``max_delay`` within ``[min_delay_ms, max_delay_ms]``:
-    a batch that fills to ``capacity`` *before* its deadline shrinks the
-    window (waiting longer buys no fill, only latency); a deadline flush
-    below ``target_fill`` grows it (the arrival rate needs a longer window
-    to amortize).  Flushes between those regimes — and lifecycle flushes at
-    close — leave the window unchanged.  All timing flows through the
-    injectable ``clock``, so every decision replays deterministically.
-
-    >>> ctl = DelayController(1.0, 8.0, clock=ManualClock())
-    >>> ctl.delay_ms                                     # starts patient
-    8.0
-    >>> ctl.observe_flush(n_requests=8, capacity=8, deadline_hit=False)
-    >>> ctl.delay_ms                                     # filled early: shrink
-    4.0
-    >>> ctl.observe_flush(n_requests=1, capacity=8, deadline_hit=True)
-    >>> ctl.delay_ms                                     # flushed sparse: grow
-    6.0
-    """
-
-    def __init__(
-        self,
-        min_delay_ms: float,
-        max_delay_ms: float,
-        *,
-        clock: Callable[[], float] = time.monotonic,
-        shrink: float = 0.5,
-        grow: float = 1.5,
-        target_fill: float = 0.5,
-        rate_window: int = 256,
-    ):
-        if not 0 < min_delay_ms <= max_delay_ms:
-            raise ValueError("need 0 < min_delay_ms <= max_delay_ms")
-        if not 0 < shrink < 1 < grow:
-            raise ValueError("need 0 < shrink < 1 < grow")
-        if not 0 < target_fill <= 1:
-            raise ValueError("need 0 < target_fill <= 1")
-        self.min_delay_s = min_delay_ms / 1e3
-        self.max_delay_s = max_delay_ms / 1e3
-        self.shrink = shrink
-        self.grow = grow
-        self.target_fill = target_fill
-        self._clock = clock
-        self._delay_s = self.max_delay_s  # start patient: latency floor is
-        self._arrivals: deque = deque(maxlen=rate_window)  # opt-in, fill is not
-        self._lock = threading.Lock()
-        self.shrinks = 0
-        self.grows = 0
-
-    @property
-    def delay_s(self) -> float:
-        return self._delay_s
-
-    @property
-    def delay_ms(self) -> float:
-        return self._delay_s * 1e3
-
-    def note_arrival(self) -> None:
-        """Record one request arrival (timestamped on the injected clock)."""
-        with self._lock:
-            self._arrivals.append(self._clock())
-
-    def arrival_rate(self) -> float:
-        """Requests/second over the rolling arrival window (0.0 until two
-        arrivals at distinct clock readings)."""
-        with self._lock:
-            if len(self._arrivals) < 2:
-                return 0.0
-            span = self._arrivals[-1] - self._arrivals[0]
-            return (len(self._arrivals) - 1) / span if span > 0 else 0.0
-
-    def observe_flush(
-        self, *, n_requests: int, capacity: int, deadline_hit: bool
-    ) -> None:
-        """Adapt to one flushed batch: shrink on an early full batch, grow on
-        a sparse deadline flush, hold otherwise."""
-        with self._lock:
-            if not deadline_hit and n_requests >= capacity:
-                self._delay_s = max(self.min_delay_s, self._delay_s * self.shrink)
-                self.shrinks += 1
-            elif deadline_hit and n_requests < self.target_fill * capacity:
-                self._delay_s = min(self.max_delay_s, self._delay_s * self.grow)
-                self.grows += 1

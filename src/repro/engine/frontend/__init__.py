@@ -9,14 +9,12 @@ scheduler : ``SortFrontend`` — per-tenant weighted admission over a bounded
             ``Ticket`` / ``ShedError`` / ``BatchInfo``)
 loadgen   : reproducible open-loop load (seeded Poisson arrivals, Zipfian
             size mix, tenant skew) with deterministic ``ManualClock``
-            simulation and wall-clock replay, reporting p50/p95/p99 latency
-            and goodput under overload (``make_trace`` / ``run_load`` /
-            ``replay_wallclock`` / ``LoadReport``)
+            simulation, reporting p50/p95/p99 latency and goodput under
+            overload (``make_trace`` / ``run_load`` / ``LoadReport``)
 
 The pieces compose into the serving story docs/serving.md tells: warm the
 ladder, admit by contract, dispatch by deadline, shed with a reason, and
-prove the whole thing with the load harness — which doubles as the
-regression gate behind ``benchmarks/engine_bench.py --snapshot/--compare``.
+prove the whole thing with the load simulation.
 """
 from .loadgen import (
     Arrival,
@@ -24,7 +22,6 @@ from .loadgen import (
     linear_service_time,
     make_trace,
     payload_for,
-    replay_wallclock,
     run_load,
     zipf_shares,
 )
@@ -44,7 +41,6 @@ __all__ = [
     "linear_service_time",
     "make_trace",
     "payload_for",
-    "replay_wallclock",
     "run_load",
     "warmup",
     "zipf_shares",

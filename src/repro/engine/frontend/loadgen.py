@@ -4,16 +4,12 @@ Measuring a serving frontend honestly requires *open-loop* load — arrivals
 fire on their own schedule whether or not the server keeps up, so overload
 actually builds a backlog instead of politely self-throttling (the
 closed-loop trap).  This module generates reproducible open-loop traffic and
-replays it against a ``SortFrontend`` in two modes:
-
-* **Simulation** (``run_load``): the frontend runs on a ``ManualClock`` and
-  a ``service_time`` cost model charges simulated seconds per dispatched
-  batch.  Arrival times, sizes, payload bytes, scheduling decisions, sheds —
-  every byte of the run is a deterministic function of the seed, which is
-  what makes the p50/p95/p99 + goodput rows regression-gateable in CI.
-* **Wall clock** (``replay_wallclock``): the same trace paced in real time
-  against the real executables (dispatcher thread mode) — this is how the
-  bench measures the actual cost of a cold cache vs an AOT-warmed one.
+simulates it against a ``SortFrontend`` (``run_load``): the frontend runs
+on a ``ManualClock`` and a ``service_time`` cost model charges simulated
+seconds per dispatched batch.  Arrival times, sizes, payload bytes,
+scheduling decisions, sheds — every byte of the run is a deterministic
+function of the seed, which is what lets tests assert p50/p95/p99 latency
+and goodput under overload exactly.
 
 Traces are per-tenant Poisson processes (exponential inter-arrivals) with a
 Zipfian request-size mix over a pow2 ladder, and ``zipf_shares`` skews the
@@ -23,7 +19,6 @@ byte-for-byte same trace and payloads (tests/test_frontend.py asserts it).
 """
 from __future__ import annotations
 
-import time
 import zlib
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -38,7 +33,6 @@ __all__ = [
     "linear_service_time",
     "make_trace",
     "payload_for",
-    "replay_wallclock",
     "run_load",
     "zipf_shares",
 ]
@@ -226,7 +220,7 @@ class LoadReport:
         return out
 
     def derived(self, tenant: Optional[str] = None) -> str:
-        """The bench's machine-readable summary fragment."""
+        """One machine-readable summary line: percentiles, goodput, sheds."""
         pct = self.latency_percentiles((50, 95, 99), tenant)
         return (
             f"p50_ms={pct[50] * 1e3:.3f};p95_ms={pct[95] * 1e3:.3f};"
@@ -303,47 +297,4 @@ def run_load(
         if isinstance(exc, ShedError):
             report.sheds.append((exc.tenant, exc.reason))
     report.elapsed_s = clock()
-    return report
-
-
-def replay_wallclock(
-    frontend: SortFrontend,
-    trace: Sequence[Arrival],
-    *,
-    seed: int = 0,
-    dtype=np.int32,
-    timeout_s: float = 120.0,
-) -> LoadReport:
-    """Replay a trace in real time against the real executables.
-
-    The frontend must be running its dispatcher thread (``start()``).
-    Arrival pacing sleeps until each trace time; latencies come from the
-    frontend's (real) clock stamps.  This is the bench's warm-vs-cold mode:
-    the cold run's percentiles include first-request compile stalls, the
-    AOT-warmed run's do not.
-    """
-    report = LoadReport(offered=len(trace))
-    t0 = time.perf_counter()
-    for arr in trace:
-        lag = arr.t - (time.perf_counter() - t0)
-        if lag > 0:
-            time.sleep(lag)
-        try:
-            report.tickets.append(
-                frontend.submit(arr.tenant, payload_for(arr, seed=seed,
-                                                        dtype=dtype),
-                                kind=arr.kind)
-            )
-        except ShedError as e:
-            report.sheds.append((e.tenant, e.reason))
-    deadline = time.perf_counter() + timeout_s
-    for t in report.tickets:
-        try:
-            t.future.result(timeout=max(0.0, deadline - time.perf_counter()))
-        except Exception:
-            pass  # sheds/errors are accounted below, not raised here
-        exc = t.future.exception()
-        if isinstance(exc, ShedError):
-            report.sheds.append((exc.tenant, exc.reason))
-    report.elapsed_s = time.perf_counter() - t0
     return report

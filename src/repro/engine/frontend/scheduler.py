@@ -1,9 +1,9 @@
 """SLO-aware multi-tenant scheduler over the batched sort service.
 
-``AsyncSortService`` (repro.engine.queue) batches well but treats every
-caller identically: one FIFO, one flush window, block-or-reject
-backpressure.  A serving front end shared by multiple tenants needs three
-things that FIFO can't give:
+``SortService.submit`` batches only what one caller hands it in one call.
+A serving front end queues single requests from many callers and coalesces
+them across callers — and, shared by multiple tenants, it needs three
+things a plain FIFO can't give:
 
 * **priority classes** — an interactive tenant's requests must dispatch
   before a batch tenant's, full stop;
@@ -12,7 +12,7 @@ things that FIFO can't give:
   single-server policy for feasible deadline sets);
 * **an explicit load-shed policy** — when the bounded backlog saturates,
   *somebody* must be told "no", immediately, with a reason, and the refusal
-  must be attributed to the right tenant (``QueueStats.shed``) instead of
+  must be attributed to the right tenant (``ServiceStats.shed``) instead of
   silently inflating everyone's tail latency.
 
 ``SortFrontend`` implements exactly that on top of ``SortService``'s
@@ -44,8 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from ..queue import QueueStats
-from ..service import SortService
+from ..service import ServiceStats, SortService
 from .warmup import WarmupReport, warmup
 
 __all__ = ["Tenant", "ShedError", "Ticket", "BatchInfo", "SortFrontend"]
@@ -86,7 +85,7 @@ class ShedError(RuntimeError):
     weighted backlog slice is full), ``'global_backlog'`` (the whole bounded
     backlog is full), or ``'deadline'`` (the request expired in queue before
     dispatch).  The same (tenant, reason) pair lands in
-    ``QueueStats.shed`` so overload is attributable after the fact.
+    ``ServiceStats.shed`` so overload is attributable after the fact.
 
     >>> ShedError("batch", "tenant_backlog").reason
     'tenant_backlog'
@@ -229,9 +228,6 @@ class SortFrontend:
         if maxsize < 1:
             raise ValueError("maxsize must be >= 1")
         self.service = service if service is not None else SortService()
-        if not isinstance(self.service.stats, QueueStats):
-            # widen in place, same trick as AsyncSortService: one shared ledger
-            self.service.stats = QueueStats(**vars(self.service.stats))
         self.tenants: Dict[str, Tenant] = {}
         for t in tenants:
             if t.name in self.tenants:
@@ -268,7 +264,7 @@ class SortFrontend:
 
     # ------------------------------------------------------------ lifecycle ---
     @property
-    def stats(self) -> QueueStats:
+    def stats(self) -> ServiceStats:
         """The shared service ledger (batches, sheds, per-tenant tallies)."""
         return self.service.stats
 
@@ -306,7 +302,7 @@ class SortFrontend:
             self._work.notify_all()
         if self._started:
             self._thread.join(timeout=30)
-        self.run_until_idle()  # pump-mode users: drain synchronously
+        self.poll()  # pump-mode users: drain synchronously
 
     def __enter__(self) -> "SortFrontend":
         return self
@@ -331,7 +327,7 @@ class SortFrontend:
         defaults to ``now + tenant.slo_ms`` (or no deadline for tenants
         without an SLO).  Validation errors raise synchronously; admission
         refusals raise ``ShedError`` with the reason and are attributed to
-        the tenant in ``QueueStats.shed``.
+        the tenant in ``ServiceStats.shed``.
         """
         cfg = self.tenants.get(tenant)
         if cfg is None:
@@ -449,8 +445,6 @@ class SortFrontend:
         while self.pump() is not None:
             n += 1
         return n
-
-    run_until_idle = poll
 
     def _dispatch_loop(self) -> None:
         while True:

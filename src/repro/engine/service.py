@@ -8,9 +8,9 @@ hot path performs **zero** jax tracing/lowering — the property the engine
 tests assert with jax's compilation counters.
 
 The group/pad/execute core lives in ``_run_group`` so the sync ``submit``
-path and the async micro-batching queue (``repro.engine.queue``) share one
-implementation — the queue coalesces requests *across* callers into the same
-per-(bucket, dtype, kind) groups this module executes.
+path and the multi-tenant ``SortFrontend`` (``repro.engine.frontend``) share
+one implementation — the frontend coalesces requests *across* callers into
+the same per-(bucket, dtype, kind) groups this module executes.
 
 Plans come from the ``Planner``: the per-bucket local sort recipe is the
 tuned shared-memory plan for that (bucket, dtype) cell (a serving front door
@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -41,7 +42,8 @@ _KINDS = ("sort", "argsort", "sort_kv")
 
 @dataclass
 class ServiceStats:
-    """Rolling counters for one ``SortService`` (requests, padding, compiles).
+    """Rolling counters for one ``SortService`` (requests, padding, compiles)
+    and for the ``SortFrontend`` that queues in front of it.
 
     ``elapsed_s`` is *busy* wall time: the union of the per-batch execution
     spans, with overlaps between concurrent submitters merged — so
@@ -60,8 +62,22 @@ class ServiceStats:
     balanced partitions, values past the learner's ``promote_ratio`` mean
     promotion is (or soon will be) in play.
 
+    The frontend's fields: ``fill_ratios`` / ``batch_sizes`` /
+    ``queue_latency_s`` are rolling windows (bounded deques), so a
+    long-lived service reports recent steady state rather than lifetime
+    averages; ``shed`` attributes every load-shed to the tenant that
+    suffered it and the reason it fired, and ``tenant_served`` tallies each
+    tenant's served requests — overload debugging starts from "who was
+    shed, and why", not from a global counter.
+
     >>> ServiceStats(keys_in=100, elapsed_s=2.0).throughput_keys_per_s()
     50.0
+    >>> s = ServiceStats()
+    >>> s.observe_batch(n_requests=6, capacity=8, latencies=[0.002] * 6)
+    >>> round(s.fill_ratio(), 2)
+    0.75
+    >>> s.latency_percentiles()[50]
+    0.002
     """
 
     requests: int = 0
@@ -74,6 +90,16 @@ class ServiceStats:
     overflow_retries: int = 0
     recompiles: int = 0
     peak_mean_ratio: float = 0.0
+    enqueued: int = 0
+    coalesced_batches: int = 0
+    coalesced_requests: int = 0
+    fill_ratios: deque = field(default_factory=lambda: deque(maxlen=1024), repr=False)
+    batch_sizes: deque = field(default_factory=lambda: deque(maxlen=1024), repr=False)
+    queue_latency_s: deque = field(
+        default_factory=lambda: deque(maxlen=8192), repr=False
+    )
+    shed: Dict[str, Dict[str, int]] = field(default_factory=dict, repr=False)
+    tenant_served: Dict[str, int] = field(default_factory=dict, repr=False)
     _busy_until: float = field(default=0.0, repr=False, compare=False)
 
     def throughput_keys_per_s(self) -> float:
@@ -92,6 +118,42 @@ class ServiceStats:
         """
         self.elapsed_s += max(0.0, t1 - max(t0, self._busy_until))
         self._busy_until = max(self._busy_until, t1)
+
+    def observe_shed(self, tenant: str, reason: str) -> None:
+        """Attribute one load-shed to ``tenant`` with its ``reason``
+        (``'tenant_backlog'`` / ``'global_backlog'`` / ``'deadline'``)."""
+        per = self.shed.setdefault(tenant, {})
+        per[reason] = per.get(reason, 0) + 1
+
+    def shed_total(self, tenant: Optional[str] = None) -> int:
+        """Total sheds — for one tenant, or across all tenants."""
+        tenants = [tenant] if tenant is not None else list(self.shed)
+        return sum(sum(self.shed.get(t, {}).values()) for t in tenants)
+
+    def observe_batch(self, *, n_requests: int, capacity: int, latencies) -> None:
+        """Record one dispatched batch (size, fill vs ``max_batch``, and
+        each member request's submit-to-done latency)."""
+        self.coalesced_batches += 1
+        self.coalesced_requests += n_requests
+        self.batch_sizes.append(n_requests)
+        self.fill_ratios.append(n_requests / capacity if capacity else 0.0)
+        self.queue_latency_s.extend(latencies)
+
+    def fill_ratio(self) -> float:
+        """Mean batch-fill ratio (requests per batch / max_batch) over the
+        rolling window; 0.0 before any batch has run."""
+        if not self.fill_ratios:
+            return 0.0
+        return sum(self.fill_ratios) / len(self.fill_ratios)
+
+    def latency_percentiles(self, ps=(50, 90, 99)) -> Dict[int, float]:
+        """{percentile: seconds} over the rolling latency window."""
+        lat = sorted(self.queue_latency_s)
+        if not lat:
+            return {p: 0.0 for p in ps}
+        return {
+            p: lat[min(len(lat) - 1, round(p / 100 * (len(lat) - 1)))] for p in ps
+        }
 
 
 def _np_sentinel(dtype: np.dtype, *, largest: bool):
@@ -297,7 +359,7 @@ class SortService:
         """Pad one group (all ``reqs`` share ``gk``) and run its executable.
 
         This is the whole hot path — numpy pad, one AOT executable call,
-        numpy slice-out — shared verbatim by ``submit`` and the async queue.
+        numpy slice-out — shared verbatim by ``submit`` and ``SortFrontend``.
         Returns one result per request, in the given order.
         """
         t0 = time.perf_counter()

@@ -13,8 +13,7 @@ and record:
   * collective bytes   — parsed from the optimized HLO (all-gather,
     all-reduce, reduce-scatter, all-to-all, collective-permute)
 
-Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json; benchmarks/
-roofline.py consumes them.
+Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k --mesh pod

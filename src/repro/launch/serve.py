@@ -4,15 +4,13 @@ Greedy/temperature sampling over the vocab-parallel logits; the decode loop
 uses the serving top-k from the sort engine (repro.engine.topk, a stable
 descending argsort) — the serving-path integration from DESIGN.md §3.
 
-``--topk-queue`` routes each row's top-k through the async micro-batching
-queue instead (repro.engine.AsyncSortService): every row is an independent
-single-request producer, and the queue coalesces them back into one
+``--topk-queue`` routes each row's top-k through a one-tenant
+``repro.engine.SortFrontend`` instead: every row is an independent
+single-request producer, and the frontend coalesces them back into one
 executable call per step — the serving shape docs/serving.md describes,
-with queue stats printed at exit.  ``--adaptive`` (implies ``--topk-queue``)
-lets a ``DelayController`` move the flush window with the observed arrival
-rate instead of pinning ``max_delay_ms``; ``--stats`` prints the full
-service ledger, including the ``overflow_retries`` / ``recompiles``
-exchange-path counters that previously vanished from serving telemetry.
+with frontend stats printed at exit.  ``--stats`` (implies ``--topk-queue``)
+also prints the full service ledger, including the ``overflow_retries`` /
+``recompiles`` exchange-path counters.
 
 ``--moe`` serves MoE expert routing through the adaptive exchange engine
 instead of decoding: a (deliberately skew-able, ``--moe-skew``) router
@@ -24,17 +22,17 @@ learned factor).  Point ``$REPRO_SORT_PLANS`` at a JSON file and the
 learned capacity survives restarts — the second serve run's first step
 already sizes expert buffers right (docs/exchange.md).
 
-``--tenants web:3:0,batch:1:1`` routes the top-k path through the
-multi-tenant SLO frontend instead (``repro.engine.frontend.SortFrontend``):
-decode rows are assigned round-robin across the named tenants (weight and
-priority per spec), each stamped with the ``--slo-ms`` deadline, and the
-exit line reports per-tenant served counts and SLO misses.  ``--warmup``
-AOT-compiles the vocab-size argsort ladder before traffic so the first
-decode step pays zero fresh compiles (docs/serving.md).
+``--tenants web:3:0,batch:1:1`` serves the top-k path through a
+multi-tenant frontend instead: decode rows are assigned round-robin across
+the named tenants (weight and priority per spec), each stamped with the
+``--slo-ms`` deadline, and the exit line reports per-tenant served counts
+and SLO misses.  ``--warmup`` (implies ``--topk-queue``) AOT-compiles the
+vocab-size argsort ladder before traffic so the first decode step pays
+zero fresh compiles (docs/serving.md).
 
 Usage:
   python -m repro.launch.serve --arch qwen3-0.6b --reduced --batch 4 \
-      --prompt-len 32 --gen 16 [--topk-queue] [--adaptive] [--stats]
+      --prompt-len 32 --gen 16 [--topk-queue] [--stats]
   python -m repro.launch.serve --moe --batch 4 --prompt-len 64 --gen 8 \
       --experts 8 --moe-skew 6.0 --stats
   python -m repro.launch.serve --reduced --batch 4 --gen 8 \
@@ -50,38 +48,33 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ARCHS, reduced
-from repro.engine import topk
+from repro.engine import SortFrontend, Tenant, topk
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import ShardCtx, model_init
 from repro.train.steps import prefill_step, serve_decode_step
 
 
 def sample_next(logits: jax.Array, key, *, temperature: float, top_k: int,
-                queue=None, frontend=None, tenants=(), ticket_log=None):
+                frontend=None, tenants=(), ticket_log=None):
     """(B, V) logits -> (B,) token ids. top_k via the engine's stable argsort
     (same tie behaviour as lax.top_k; the serving-path integration).
 
-    With ``queue=`` (an ``AsyncSortService``) each row becomes one
-    ``submit_async(kind='argsort', ascending=False)`` request; the queue
-    coalesces the B rows into a single executable call per decode step.
-    With ``frontend=`` (a ``SortFrontend``) rows are instead submitted
-    round-robin across ``tenants`` — each row carries its tenant's SLO
-    deadline, and admitted tickets land in ``ticket_log`` so the driver can
-    report per-tenant SLO misses at exit.
+    With ``frontend=`` (a ``SortFrontend``) each row becomes one
+    ``submit(kind='argsort', ascending=False)`` request, round-robin across
+    ``tenants``; the frontend coalesces the B rows into a single executable
+    call per decode step.  Each row carries its tenant's SLO deadline, and
+    admitted tickets land in ``ticket_log`` so ``main`` can report
+    per-tenant SLO misses at exit.
     """
-    if frontend is not None or queue is not None:
+    if frontend is not None:
         rows = np.asarray(logits, np.float32)
-        if frontend is not None:
-            futs = [
-                frontend.submit(tenants[i % len(tenants)], r,
-                                kind="argsort", ascending=False)
-                for i, r in enumerate(rows)
-            ]
-            if ticket_log is not None:
-                ticket_log.extend(futs)
-        else:
-            futs = [queue.submit_async(r, kind="argsort", ascending=False)
-                    for r in rows]
+        futs = [
+            frontend.submit(tenants[i % len(tenants)], r,
+                            kind="argsort", ascending=False)
+            for i, r in enumerate(rows)
+        ]
+        if ticket_log is not None:
+            ticket_log.extend(futs)
         order = np.stack([np.asarray(f.result())[:top_k] for f in futs])
         idx = jnp.asarray(order.astype(np.int32))
         if temperature <= 0:
@@ -204,13 +197,8 @@ def main(argv=None):
     ap.add_argument("--top-k", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--topk-queue", action="store_true",
-                    help="route per-row top-k through the AsyncSortService "
-                         "micro-batching queue (docs/serving.md)")
-    ap.add_argument("--adaptive", action="store_true",
-                    help="adapt the queue's flush window to the arrival rate "
-                         "(DelayController; implies --topk-queue)")
-    ap.add_argument("--min-delay-ms", type=float, default=0.1,
-                    help="lower bound of the adaptive flush window")
+                    help="route per-row top-k through a one-tenant "
+                         "SortFrontend (docs/serving.md)")
     ap.add_argument("--stats", action="store_true",
                     help="print the full service ledger at exit, incl. the "
                          "overflow_retries / recompiles exchange counters "
@@ -228,10 +216,10 @@ def main(argv=None):
                     help="router logit bias onto a hot expert subset (0 = "
                          "uniform routing, nothing for the loop to learn)")
     ap.add_argument("--tenants", default="",
-                    help="serve the top-k path through the multi-tenant "
-                         "SLO frontend (repro.engine.frontend.SortFrontend); "
-                         "comma-separated name[:weight[:priority]] specs, "
-                         "decode rows assigned round-robin (docs/serving.md)")
+                    help="serve the top-k path through a multi-tenant "
+                         "SortFrontend; comma-separated "
+                         "name[:weight[:priority]] specs, decode rows "
+                         "assigned round-robin (docs/serving.md)")
     ap.add_argument("--slo-ms", type=float, default=None,
                     help="per-request deadline budget for --tenants rows; "
                          "late rows are still answered (serving must emit a "
@@ -239,19 +227,17 @@ def main(argv=None):
     ap.add_argument("--warmup", action="store_true",
                     help="AOT-compile the serving sort cells (vocab-size "
                          "argsort across the batch ladder) before traffic, "
-                         "so the first decode step pays zero compiles")
+                         "so the first decode step pays zero compiles "
+                         "(implies --topk-queue)")
     args = ap.parse_args(argv)
 
     if args.moe:
         return run_moe_serving(args)
 
     frontend = None
-    fe_tenants: list = []
     fe_tickets: list = []
-    qsvc = None
+    specs: list = []
     if args.tenants:
-        from repro.engine import SortFrontend, Tenant
-        specs = []
         for spec in args.tenants.split(","):
             parts = spec.split(":")
             specs.append(Tenant(
@@ -260,18 +246,14 @@ def main(argv=None):
                 priority=int(parts[2]) if len(parts) > 2 else 0,
                 slo_ms=args.slo_ms,
             ))
+    elif args.topk_queue or args.stats or args.warmup:
+        specs = [Tenant("decode")]
+    if specs:
         # shed_expired=False: a decode row must produce a token no matter
         # what, so late rows are served and the miss is counted instead
         frontend = SortFrontend(tenants=specs, max_batch=args.batch,
                                 shed_expired=False, start=True)
-        fe_tenants = [t.name for t in specs]
-    elif args.topk_queue or args.adaptive or args.stats:
-        from repro.engine import AsyncSortService
-        qsvc = AsyncSortService(
-            max_batch=args.batch,
-            max_delay_ms=2.0,
-            min_delay_ms=args.min_delay_ms if args.adaptive else None,
-        )
+    fe_tenants = [t.name for t in specs]
 
     cfg = ARCHS[args.arch]
     if args.reduced:
@@ -281,17 +263,8 @@ def main(argv=None):
         # AOT-warm every executable the decode loop's top-k can touch: a
         # descending float32 argsort of one vocab row, at every pow2 batch
         # bucket up to --batch (partial flushes produce partial batches)
-        from repro.engine.frontend import warmup as engine_warmup
-        svc = frontend.service if frontend is not None else (
-            qsvc.service if qsvc is not None else None
-        )
-        if svc is None:
-            from repro.engine import AsyncSortService
-            qsvc = AsyncSortService(max_batch=args.batch, max_delay_ms=2.0)
-            svc = qsvc.service
-        rep = engine_warmup(svc, cells=[(cfg.vocab_size, "float32")],
-                            kinds=("argsort",), ascending=(False,),
-                            max_batch=args.batch)
+        rep = frontend.warmup(cells=[(cfg.vocab_size, "float32")],
+                              kinds=("argsort",), ascending=(False,))
         print(rep.summary())
 
     ctx = ShardCtx()
@@ -320,7 +293,7 @@ def main(argv=None):
     decode = jax.jit(lambda p, t, c: serve_decode_step(p, cfg, t, c, ctx=ctx))
     out_tokens = []
     tok = sample_next(logits, key, temperature=args.temperature,
-                      top_k=args.top_k, queue=qsvc, frontend=frontend,
+                      top_k=args.top_k, frontend=frontend,
                       tenants=fe_tenants, ticket_log=fe_tickets)
     out_tokens.append(tok)
     t0 = time.time()
@@ -328,7 +301,7 @@ def main(argv=None):
         key, sub = jax.random.split(key)
         lg, cache = decode(params, tok[:, None], cache)
         tok = sample_next(lg[:, 0], sub, temperature=args.temperature,
-                          top_k=args.top_k, queue=qsvc, frontend=frontend,
+                          top_k=args.top_k, frontend=frontend,
                           tenants=fe_tenants, ticket_log=fe_tickets)
         out_tokens.append(tok)
     jax.block_until_ready(tok)
@@ -350,31 +323,13 @@ def main(argv=None):
               f"shed={st.shed_total()}")
         if args.stats:
             pct = st.latency_percentiles()
-            print(f"frontend-stats: requests={st.requests} "
+            print(f"service-stats: requests={st.requests} "
                   f"keys_in={st.keys_in} cache_hits={st.cache_hits} "
-                  f"queue p50={pct[50]*1e3:.2f} ms p99={pct[99]*1e3:.2f} ms "
+                  f"overflow_retries={st.overflow_retries} "
+                  f"recompiles={st.recompiles} "
+                  f"peak_mean_ratio={st.peak_mean_ratio:.2f} "
+                  f"latency p50={pct[50]*1e3:.2f} ms p99={pct[99]*1e3:.2f} ms "
                   f"throughput={st.throughput_keys_per_s():.0f} keys/s")
-    if qsvc is not None:
-        qsvc.close()
-        qs = qsvc.stats
-        pct = qs.latency_percentiles()
-        print(f"sort-queue: batches={qs.coalesced_batches} "
-              f"fill={qs.fill_ratio():.2f} compiles={qs.compiles} "
-              f"queue p50={pct[50]*1e3:.2f} ms p99={pct[99]*1e3:.2f} ms")
-        if qsvc.delay is not None:
-            print(f"adaptive-delay: window={qsvc.delay.delay_ms:.3f} ms "
-                  f"(bounds [{qsvc.delay.min_delay_s*1e3:.3f}, "
-                  f"{qsvc.delay.max_delay_s*1e3:.3f}]) "
-                  f"shrinks={qsvc.delay.shrinks} grows={qsvc.delay.grows} "
-                  f"arrival_rate={qsvc.delay.arrival_rate():.1f}/s")
-        if args.stats:
-            print(f"service-stats: requests={qs.requests} batches={qs.batches} "
-                  f"keys_in={qs.keys_in} compiles={qs.compiles} "
-                  f"cache_hits={qs.cache_hits} "
-                  f"overflow_retries={qs.overflow_retries} "
-                  f"recompiles={qs.recompiles} "
-                  f"peak_mean_ratio={qs.peak_mean_ratio:.2f} "
-                  f"throughput={qs.throughput_keys_per_s():.0f} keys/s")
     assert gen.min() >= 0 and gen.max() < cfg.vocab_size, "pad-vocab leak!"
     return gen
 

@@ -95,7 +95,7 @@ def router_probs(p: Params, cfg: MoEConfig, x: jax.Array):
 
 def collapse_router(p: Params, logit_scale: float = 10.0) -> Params:
     """A copy of ``p`` whose router concentrates routing on a few low-index
-    experts — the worst-case skew benchmarks/demos/tests use to exercise the
+    experts — the worst-case skew demos and tests use to exercise the
     capacity-learning loop.
 
     The single nonzero router column gives expert 0 logit

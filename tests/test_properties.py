@@ -5,7 +5,8 @@ generator: random lengths and dtypes crossed with an adversarial case matrix
 (duplicate-heavy, pre-sorted, reverse-sorted, all-equal, ±inf floats / int
 extremes).  Every path — ``api.sort`` across the paper's models and all
 ``local_impl`` engines, ``engine.kv`` (sort_kv / argsort / topk), and the
-sync + async serving services — must reproduce the oracle exactly.
+sync service and the queued ``SortFrontend`` over it — must reproduce the
+oracle exactly.
 
 Runs under real ``hypothesis`` when installed (CI) with a fixed,
 derandomized profile so CI stays deterministic; falls back to the seeded
@@ -22,7 +23,7 @@ except ImportError:  # bare container — requirements-dev.txt installs the real
 
 from conftest import run_with_devices
 from repro.core import sort
-from repro.engine import AsyncSortService, SortService, argsort, sort_pairs, topk
+from repro.engine import SortFrontend, SortService, Tenant, argsort, sort_pairs, topk
 from repro.exchange import splitter_bucket, splitters_from_sample
 
 # fixed + derandomized: the same examples on every CI run
@@ -78,14 +79,6 @@ def np_rev(k: np.ndarray) -> np.ndarray:
 # one service per module: examples share the compiled-executable cache, so
 # the harness exercises the steady state instead of recompiling per example
 SERVICE = SortService()
-_ASYNC = None
-
-
-def async_service() -> AsyncSortService:
-    global _ASYNC
-    if _ASYNC is None:
-        _ASYNC = AsyncSortService(SERVICE, max_batch=8, max_delay_ms=1.0)
-    return _ASYNC
 
 
 # --------------------------------------------------------- api.sort (A/B) ---
@@ -180,18 +173,19 @@ def test_sort_service_ragged_batches(lens, case, dtype, seed):
 
 
 @given(st.lists(st.integers(1, 600), min_size=1, max_size=5), cases, dtypes, seeds)
-def test_async_sort_service_ragged_batches(lens, case, dtype, seed):
-    """AsyncSortService futures == the sync oracle, interleaved kinds."""
-    svc = async_service()
+def test_frontend_ragged_batches(lens, case, dtype, seed):
+    """SortFrontend tickets == the sync oracle, interleaved kinds."""
+    fe = SortFrontend(SERVICE, tenants=[Tenant("t")], max_batch=8)
     reqs = [make_keys(case, n, dtype, seed + j) for j, n in enumerate(lens)]
-    futs = [(r, "sort", svc.submit_async(r)) for r in reqs]
-    futs += [(r, "argsort", svc.submit_async(r, kind="argsort")) for r in reqs]
+    futs = [(r, "sort", fe.submit("t", r)) for r in reqs]
+    futs += [(r, "argsort", fe.submit("t", r, kind="argsort")) for r in reqs]
     futs += [
         (r, "sort_kv",
-         svc.submit_async(r, kind="sort_kv",
-                          values=np.arange(len(r), dtype=np.int32)))
+         fe.submit("t", r, kind="sort_kv",
+                   values=np.arange(len(r), dtype=np.int32)))
         for r in reqs
     ]
+    fe.poll()
     for r, kind, f in futs:
         ref = np.argsort(r, kind="stable")
         if kind == "sort":

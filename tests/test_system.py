@@ -29,15 +29,20 @@ def test_serve_driver_end_to_end():
     assert gen.shape == (2, 4)
 
 
-def test_serve_driver_topk_queue_matches_direct_path():
-    """--topk-queue (per-row argsort through AsyncSortService) samples the
-    same tokens as the direct engine.topk path — same seed, same model."""
+def test_serve_driver_topk_queue_matches_direct_path(capsys):
+    """--topk-queue (per-row argsort through the one-tenant SortFrontend)
+    samples the same tokens as the direct engine.topk path — same seed,
+    same model — and serves every row through that tenant."""
     args = ["--arch", "qwen3-0.6b", "--reduced", "--batch", "2",
             "--prompt-len", "12", "--gen", "4"]
     direct = serve_main(args)
+    capsys.readouterr()
     queued = serve_main(args + ["--topk-queue"])
+    out = capsys.readouterr().out
     assert queued.shape == (2, 4)
     assert (queued == direct).all()
+    assert "tenants[decode=8]" in out              # 2 rows x 4 steps
+    assert "shed=0" in out
 
 
 def test_serve_driver_multi_tenant_frontend_matches_direct_path(capsys):

@@ -9,6 +9,7 @@ of redefining it per file.
 from __future__ import annotations
 
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -33,6 +34,21 @@ def run_with_devices(code: str, n: int = 8) -> str:
     )
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     return out.stdout
+
+
+_HLO_OP = re.compile(r"= (?P<shape>.*?) (?P<op>[a-z][a-z-]*)\((?P<rest>.*)$")
+
+
+def ops_under(hlo: str, scope: str):
+    """(opcode, result shape) of every HLO instruction whose ``op_name`` lies
+    under ``scope``, fused computations included."""
+    out = []
+    for line in hlo.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = _HLO_OP.search(line)
+        if name and op and scope in name.group(1).split("/"):
+            out.append((op.group("op"), op.group("shape")))
+    return out
 
 
 @pytest.fixture

@@ -98,6 +98,30 @@ def test_merge_adjacent_round():
     assert (out == expect).all()
 
 
+@pytest.mark.parametrize("lead,pairs", [((), 1), ((2,), 1), ((), 4), ((2,), 4), ((), 16)])
+def test_merge_adjacent_is_stable_with_values(lead, pairs):
+    """Rows of the round's sort (``lead`` x ``pairs``: 1, 2, 4, 8, 16) all
+    merge stably: keys as ``np.sort``, equal keys left run first, with their
+    values."""
+    rng = np.random.default_rng(len(lead) * 100 + pairs)
+    w = 32
+    keys = rng.integers(0, 6, size=(*lead, pairs, 2, w)).astype(np.int32)
+    keys = np.sort(keys, axis=-1)  # two sorted runs a pair
+    vals = {"pos": np.arange(keys.size, dtype=np.int32).reshape(keys.shape),
+            "f": rng.standard_normal(keys.shape).astype(np.float32)}
+    n = pairs * 2 * w
+    out, mvals = merge_adjacent(
+        jnp.asarray(keys.reshape(*lead, n)), w,
+        {k: jnp.asarray(v.reshape(*lead, n)) for k, v in vals.items()},
+    )
+    rows = keys.reshape(-1, 2 * w)
+    order = np.argsort(rows, axis=-1, kind="stable")
+    np.testing.assert_array_equal(np.asarray(out).reshape(-1, 2 * w), np.sort(rows, axis=-1))
+    for k, v in vals.items():
+        want = np.take_along_axis(v.reshape(-1, 2 * w), order, axis=-1)
+        np.testing.assert_array_equal(np.asarray(mvals[k]).reshape(-1, 2 * w), want)
+
+
 def test_bitonic_topk_matches_lax():
     rng = np.random.default_rng(2)
     x = rng.standard_normal((5, 64)).astype(np.float32)

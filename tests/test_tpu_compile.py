@@ -11,7 +11,6 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
-import re
 
 import numpy as np
 import pytest
@@ -19,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from conftest import ops_under
 from repro.core.cluster_sort import _compiled_cluster_sort
 from repro.engine.planner import PALLAS_BLOCK_SWEEP
 from repro.exchange import slab_geometry
@@ -103,27 +103,12 @@ def test_cluster_sort_compiles_for_four_chips(compiled_cluster, mode):
     assert 0 < per_device < HBM_BYTES, per_device
 
 
-_HLO_OP = re.compile(r"= (?P<shape>.*?) (?P<op>[a-z][a-z-]*)\((?P<rest>.*)$")
-
-
-def _ops_under(hlo: str, scope: str):
-    """(opcode, result shape) of every HLO instruction whose ``op_name`` lies
-    under ``scope``, fused computations included."""
-    out = []
-    for line in hlo.splitlines():
-        name = re.search(r'op_name="([^"]*)"', line)
-        op = _HLO_OP.search(line)
-        if name and op and scope in name.group(1).split("/"):
-            out.append((op.group("op"), op.group("shape")))
-    return out
-
-
 @pytest.mark.parametrize("mode,max_sorts", [("splitters", 1), ("sample", 2)])
 def test_cluster_sort_partition_is_gather_free(compiled_cluster, mode, max_sorts):
     """The keys-only partition sorts the shard and slices it: no gather and
     no scatter under ``repro.partition``, and at most one sort of a shard's
     m keys (two in sample mode, whose composite splitters sort (key, id))."""
-    ops = _ops_under(compiled_cluster(mode).as_text(), "repro.partition")
+    ops = ops_under(compiled_cluster(mode).as_text(), "repro.partition")
     assert ops, "no instruction carries the repro.partition scope"
     assert not [o for o in ops if o[0] in ("gather", "scatter")], ops
     m = CLUSTER_N // 4
@@ -141,7 +126,7 @@ def test_compaction_is_gather_free_and_fits_hbm(four_chip_mesh):
     slab = jax.ShapeDtypeStruct((total,), jnp.int32, sharding=sharding)
     valid = jax.ShapeDtypeStruct((total,), jnp.bool_, sharding=sharding)
     compiled = _compiled_compact(four_chip_mesh, "x", n).lower(slab, valid).compile()
-    ops = _ops_under(compiled.as_text(), "repro.compact")
+    ops = ops_under(compiled.as_text(), "repro.compact")
     assert ops, "no instruction carries the repro.compact scope"
     assert not [o for o in ops if o[0] in ("gather", "scatter")], ops
     assert [o for o in ops if o[0] == "dynamic-slice"], ops

@@ -14,8 +14,9 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from conftest import REPO, run_with_devices
+from conftest import REPO, ops_under, run_with_devices
 
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -37,10 +38,34 @@ def test_model_b_sorts_carry_tile_sort_and_merge_scopes():
     x = jnp.zeros((1 << 12,), jnp.int32)
     text = shared_memory_sort.lower(x, n_threads=8).compile().as_text()
     found = scopes_of(text, "sort")
-    # one tile sort, then log2(8) merge rounds
+    # one tile sort, then log2(8) merge rounds of 4, 2 and 1 run pairs, each
+    # pair sorted on its own (fewer than 8 rows a round)
     assert found.count("repro.tile_sort") == 1, found
-    assert found.count("repro.merge") == 3, found
+    assert found.count("repro.merge") == 4 + 2 + 1, found
     assert set(found) == {"repro.tile_sort", "repro.merge"}
+
+
+@pytest.mark.parametrize("shape,n_threads", [
+    ((1 << 12,), 2), ((1 << 12,), 8), ((1 << 16,), 2), ((1 << 16,), 8), ((16, 1 << 12), 8),
+])
+def test_model_b_merge_sorts_fill_eight_rows_or_run_1d(shape, n_threads):
+    """No merge-round sort has 1-7 rows along its sorted axis: a round with
+    fewer than 8 run pairs sorts each as a 1-D array, and a batch of 16 rows
+    keeps its batched sorts."""
+    from repro.core.shared_sort import shared_memory_sort
+
+    x = jnp.zeros(shape, jnp.int32)
+    text = shared_memory_sort.lower(x, n_threads=n_threads).compile().as_text()
+    dims = [[int(d) for d in re.search(r"\[([\d,]*)\]", shape_).group(1).split(",")]
+            for op, shape_ in ops_under(text, "repro.merge") if op == "sort"]
+    assert dims, "no sort carries the repro.merge scope"
+    rows = [int(np.prod(d[:-1])) for d in dims]
+    assert not [r for d, r in zip(dims, rows) if len(d) > 1 and r < 8], dims
+    if len(shape) > 1:
+        assert len(dims) == 3 and min(rows) >= 8, dims
+    else:
+        assert all(len(d) == 1 for d in dims), dims
+        assert len(dims) == n_threads - 1, dims
 
 
 def test_model_d_and_compaction_carry_their_scopes():

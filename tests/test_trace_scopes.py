@@ -98,9 +98,9 @@ def test_model_d_and_compaction_carry_their_scopes():
     assert eval(compact_scopes) == ["repro.compact"]
 
 
-def host_spans(fn, prefix="repro."):
-    """Names of the host spans under ``prefix`` that one call of ``fn`` leaves
-    in a profiler session, with their counts."""
+def host_events(fn, prefix="repro."):
+    """The host spans under ``prefix`` that one call of ``fn`` leaves in a
+    profiler session, in order: (name, arguments) each."""
     import tempfile
 
     from jax.profiler import ProfileData
@@ -112,13 +112,17 @@ def host_spans(fn, prefix="repro."):
     finally:
         jax.profiler.stop_trace()
     [pb] = glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(pb).planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events if e.name.startswith(prefix)]
+
+
+def host_spans(fn, prefix="repro."):
+    """Names of the host spans under ``prefix`` that one call of ``fn`` leaves
+    in a profiler session, with their counts."""
     names = {}
-    for plane in ProfileData.from_file(pb).planes:
-        if plane.name == "/host:CPU":
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith(prefix):
-                        names[e.name] = names.get(e.name, 0) + 1
+    for name, _ in host_events(fn, prefix):
+        names[name] = names.get(name, 0) + 1
     return names
 
 
